@@ -35,5 +35,5 @@ bench-fabric-quick:  ## distributed-fabric loadgen quick path (hard conservation
 bench-lm-delta-quick:  ## delta-ized LM cells (RWKV6 / RG-LRU) quick path (hard theta=0 bitwise-decode + >2x byte-reduction asserts, no baseline writes)
 	python -m benchmarks.lm_delta_bench --quick
 
-check-regression:  ## gate fresh fused-path wall time / bytes model vs committed baselines
-	python -m benchmarks.check_regression
+check-regression:  ## gate fresh fused-path wall time / bytes model vs committed baselines (8 host devices for the in-process fabric gate)
+	XLA_FLAGS=--xla_force_host_platform_device_count=8 python -m benchmarks.check_regression

@@ -595,23 +595,20 @@ def main() -> int:
             _gate_soak(base_soak, fresh_soak, failures, same_machine)
 
     from benchmarks import loadgen_fabric as fabric
-    if os.path.exists(fabric.FABRIC_JSON):
-        # the fabric bench must own its process: it forces an 8-device
-        # host platform via XLA_FLAGS before jax import, and this
-        # process's jax backend is already initialized without it
-        import subprocess
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (os.path.join(os.path.dirname(__file__), os.pardir,
-                                     "src"),
-                        env.get("PYTHONPATH")) if p)
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.loadgen_fabric", "--gate"],
-            cwd=os.path.join(os.path.dirname(__file__), os.pardir), env=env)
-        if proc.returncode != 0:
-            failures.append(
-                "FABRIC GATE: benchmarks.loadgen_fabric --gate failed "
-                "(see its output above)")
+    base_fabric = _load(fabric.FABRIC_JSON)
+    if base_fabric is not None:
+        # in this process, on the devices it has: a child started now
+        # could not reach an accelerator this process already holds
+        import jax
+        need, have = base_fabric["config"]["n_shards"], len(jax.devices())
+        if have < need:
+            warnings.append(
+                f"fabric gate not run: its record needs {need} devices and "
+                f"this process has {have} (on CPU, `make check-regression` "
+                f"forces {need} host devices)")
+        elif fabric.run_gate() != 0:
+            failures.append("FABRIC GATE: benchmarks.loadgen_fabric gate "
+                            "failed (see its output above)")
 
     from benchmarks import lm_delta_bench as lmd
     base_lmd = _load(lmd.BENCH_LM_DELTA_JSON)
@@ -648,4 +645,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

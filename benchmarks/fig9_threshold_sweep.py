@@ -76,4 +76,6 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run()))

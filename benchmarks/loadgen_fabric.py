@@ -32,8 +32,8 @@ are machine-bound, gated at 1.5x on the baseline's machine class.
 ``--quick`` (the ``make bench-fabric-quick`` CI stage) runs a reduced
 schedule with the same hard asserts and writes nothing; ``--gate``
 re-runs the committed config and gates fresh-vs-baseline (exit 1 on
-regression) — run as a subprocess by ``check_regression`` so the forced
-8-device host platform never leaks into the other benches' processes.
+regression). ``check_regression`` runs the same gate in its own process
+on the devices that process has.
 """
 from __future__ import annotations
 
@@ -49,7 +49,6 @@ import time
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
-import numpy as np  # noqa: E402
 
 FABRIC_JSON = os.path.join(os.path.dirname(__file__), "BENCH_fabric.json")
 
@@ -87,45 +86,13 @@ def _steady_percentile(walls, q):
     return steady[min(len(steady) - 1, int(q * len(steady)))]
 
 
-def _check_parity(arrivals, results, fleet) -> int:
-    """Bitwise-compare every completed stream against a clean same-width
-    reference engine, batching up to one tile width of streams per
-    reference run (companion streams are bitwise-neutral at fixed tile
-    width — the PR 6/7 rule — so one ``step_many`` checks B streams)."""
-    b = fleet.streams_per_shard
-    i_dim = fleet.dims.input_size
-    ref = fleet.reference_engine()
-    completed = [(i, r) for i, r in sorted(results.items())
-                 if r.status == "ok"]
-    parity_ok = 0
-    for base in range(0, len(completed), b):
-        group = completed[base:base + b]
-        t_max = max(len(arrivals[i][1]) for i, _ in group)
-        xs = np.zeros((t_max, b, i_dim), np.float32)
-        for j, (i, _) in enumerate(group):
-            frames = arrivals[i][1]
-            xs[:len(frames), j] = frames
-            # pad with the last frame: zero delta, and causality means the
-            # real prefix's outputs are unaffected
-            xs[len(frames):, j] = frames[-1]
-        ref.reset()
-        want = np.asarray(ref.step_many(xs))
-        for j, (i, r) in enumerate(group):
-            got = np.stack([np.asarray(o) for o in r.outputs])
-            assert want[:len(got), j].tobytes() == got.tobytes(), \
-                f"fabric parity: arrival {i} (shard {r.shard}, " \
-                f"replayed={r.replayed}, {len(got)} frames) diverged " \
-                "from its clean same-width reference"
-            parity_ok += 1
-    return parity_ok
-
-
 def bench_fabric_record(**cfg):
     from repro.dist.elastic import best_mesh
     from repro.dist.serving import ShardedStreamFleet
     from repro.models.gru_rnn import GruTaskConfig, init_gru_model
     from repro.quant.export import quantize_delta_model
-    from repro.serve.loadgen import poisson_arrivals, run_fabric_load
+    from repro.serve.loadgen import (fabric_parity, poisson_arrivals,
+                                     run_fabric_load)
     from repro.serve.router import RouterPolicy, StreamRouter
 
     c = {**DEFAULTS, **cfg}
@@ -179,7 +146,7 @@ def bench_fabric_record(**cfg):
     assert summary.peak_concurrent_full >= c["min_concurrent"], \
         f"peak concurrency {summary.peak_concurrent_full} on the full " \
         f"mesh never reached {c['min_concurrent']}"
-    parity_ok = _check_parity(arrivals, results, fleet)
+    parity_ok = fabric_parity(arrivals, results, fleet)
     assert parity_ok == cons["completed"]
 
     # -- deterministic (tick-exact, machine-independent) block ------------
@@ -260,8 +227,8 @@ def run_gate() -> int:
 
     The counts block is tick-exact and seeded, so it must reproduce
     EXACTLY on any machine; the p99 steady tick wall is gated at 1.5x on
-    the baseline's machine class only. Run in its own process (the forced
-    host-device count must not leak into sibling benches).
+    the baseline's machine class only. Needs ``n_shards`` devices in the
+    calling process.
     """
     if not os.path.exists(FABRIC_JSON):
         print("no committed BENCH_fabric.json; nothing to gate")
@@ -318,6 +285,8 @@ if __name__ == "__main__":
     args = ap.parse_args()
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
                                     "src"))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.gate:
         sys.exit(run_gate())
     print("\n".join(run_quick() if args.quick else run()))

@@ -252,4 +252,6 @@ def run_deltalstm(bench_json: str | None = None,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run() + run_deltagru() + run_deltalstm()))

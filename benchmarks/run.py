@@ -48,20 +48,15 @@ def _suites(quick: bool):
         # --quick`), so it is NOT repeated in --quick here
         ("soak", soak_serving.run),
     ]
-    # roofline suites are additive: an import failure there (it pulls the
-    # whole configs registry) must not take down the paper-table suites
-    try:
-        from benchmarks import roofline
-        # kernel_bench.run writes BENCH_deltagru_q8.json and
-        # BENCH_deltalstm_q8.json above, so both delta-RNN rooflines
-        # always see fresh records
-        suites.append(("roofline_deltagru", roofline.run_deltagru))
-        suites.append(("roofline_deltalstm", roofline.run_deltalstm))
-        # the LM roofline runs only when dry-run artifacts exist
-        if os.path.isdir(roofline.ART_DIR) and os.listdir(roofline.ART_DIR):
-            suites.append(("roofline", roofline.run))
-    except Exception:
-        pass
+    from benchmarks import roofline
+    # kernel_bench.run writes BENCH_deltagru_q8.json and
+    # BENCH_deltalstm_q8.json above, so both delta-RNN rooflines always
+    # see fresh records
+    suites.append(("roofline_deltagru", roofline.run_deltagru))
+    suites.append(("roofline_deltalstm", roofline.run_deltalstm))
+    # the LM roofline runs only when dry-run artifacts exist
+    if os.path.isdir(roofline.ART_DIR) and os.listdir(roofline.ART_DIR):
+        suites.append(("roofline", roofline.run))
     return suites
 
 
@@ -101,4 +96,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -247,4 +247,6 @@ if __name__ == "__main__":
     args = ap.parse_args()
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
                                     "src"))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run_quick() if args.quick else run()))

@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.dist.sharding import make_mesh
+
 
 def _factorize(n_devices: int, model_parallel: int) -> tuple[int, int]:
     """Largest model-parallel degree <= requested that divides the fleet.
@@ -33,7 +35,7 @@ def best_mesh(n_devices: int | None = None, model_parallel: int = 1):
             f"({avail} available); a zero-device mesh is a fleet death, not "
             f"a resize")
     data, mp = _factorize(n, model_parallel)
-    return jax.make_mesh((data, mp), ("data", "model"))
+    return make_mesh((data, mp), ("data", "model"))
 
 
 def scale_event(old_mesh, new_n_devices: int, model_parallel: int = 1) -> dict:

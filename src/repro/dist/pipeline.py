@@ -13,7 +13,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -32,7 +31,7 @@ def pipeline_forward(stage_fn, mesh: Mesh, axis: str, n_micro: int):
     n_stages = mesh.shape[axis]
     perm = [(i, i + 1) for i in range(n_stages - 1)]
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis), P()), out_specs=P(axis))
     def fwd(ws, xs):
         idx = jax.lax.axis_index(axis)
@@ -54,7 +53,9 @@ def pipeline_forward(stage_fn, mesh: Mesh, axis: str, n_micro: int):
             outs = jnp.where(write, updated, outs)
             return (nxt, outs), None
 
-        carry0 = (jnp.zeros_like(xs[0]), jnp.zeros_like(xs))
+        # the carry varies per stage from the first tick on
+        carry0 = jax.lax.pcast((jnp.zeros_like(xs[0]), jnp.zeros_like(xs)),
+                               (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, carry0, jnp.arange(n_ticks))
         return outs[None]              # [1, M, mb, ...] per stage
 

@@ -46,7 +46,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.elastic import best_mesh, scale_event
@@ -139,22 +138,22 @@ class ShardedStreamFleet:
         # tree-prefix specs: one P per argument/result subtree. Leaves are
         # [N, ...] (stream axis 0) or [S] (one aggregate per shard) — both
         # shard on their leading axis.
-        self._fleet_step = jax.jit(shard_map(
+        self._fleet_step = jax.jit(jax.shard_map(
             self.template._one_step_fn, mesh=self.mesh,
             in_specs=(spec, spec, spec),
             out_specs=(spec, spec, spec),
-            check_rep=False))
+            check_vma=False))
         xs_spec = self._rules.resolve(None, "batch", mesh=self.mesh)
-        self._fleet_steps = jax.jit(shard_map(
+        self._fleet_steps = jax.jit(jax.shard_map(
             self.template._steps_fn, mesh=self.mesh,
             in_specs=(spec, spec, xs_spec),
             out_specs=(xs_spec, spec, spec),
-            check_rep=False))
-        self._fleet_reset = jax.jit(shard_map(
+            check_vma=False))
+        self._fleet_reset = jax.jit(jax.shard_map(
             self.template._reset_streams_fn, mesh=self.mesh,
             in_specs=(spec, spec, spec),
             out_specs=(spec, spec),
-            check_rep=False))
+            check_vma=False))
 
     def _place(self, tree):
         return jax.tree_util.tree_map(

@@ -16,7 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
 
@@ -33,6 +33,19 @@ _DEFAULT_RULES = {
     "vocab": ("model",),
     "experts": ("model",),
 }
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``, the only kind this repo builds.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which indexing a
+    sharded result (``y[sid]``) or a gather inside ``shard_map`` must state
+    its sharding; with ``Auto`` axes the partitioner places them.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def _mesh_sizes(mesh: Mesh) -> dict:

@@ -22,7 +22,8 @@ final gate equations differ per cell. This module is that shared core:
   packer (``gates=3`` reproduces the historical GRU pack bit for bit;
   ``gates=4`` is the LSTM volume) — and its int4 sibling
   :func:`pack_delta_weights_q4`, which nibble-packs two codes per byte
-  (:func:`pack_nibbles`) so the streamed volume is half the q8 bytes;
+  along the row axis (:func:`pack_nibbles`) so the streamed volume is
+  half the q8 bytes;
 * the int8/int4 Pallas kernels + bit-identical jnp oracles for both
   builtin cells: :func:`deltagru_q8_step` / :func:`deltagru_q8_step_ref`
   (G=3, seam-routed split-candidate memories, Fig. 7 GRU activation) and
@@ -153,52 +154,74 @@ def _prep_step_operands(lay: _GruBlockGeometry, m_prev: Array, h_prev: Array,
     return d_cat, m4, hprev, n_active, active_ids
 
 
-def pack_nibbles(codes: Array, block_k: int) -> Array:
-    """Pack int4 codes (two per byte) along the last (k) dimension.
+def pack_nibbles(codes: Array, block_h: int) -> Array:
+    """Pack int4 codes (two per byte) along the row (second-to-last) axis.
 
-    The packing is *per k-block*: within each ``block_k``-wide block, byte
-    ``j`` holds column ``j`` in its low nibble and column
-    ``j + block_k//2`` in its high nibble. A kernel block of the packed
-    volume is therefore exactly one k-block (``block_k//2`` bytes), and
-    the in-register unpack is a mask/shift plus ONE lane-contiguous
-    concatenation — no per-element interleave, which TPU lanes cannot do
-    cheaply. ``codes`` must be int8 values in ``[-8, 7]`` with a last dim
-    divisible by ``block_k``; returns int8 of half the last extent.
+    The packing is *per row block*: within each ``block_h``-row block,
+    packed row ``j`` holds row ``j`` in its low nibble and row
+    ``j + block_h//2`` in its high nibble, column by column. A kernel
+    weight block of the packed volume is then ``[G, block_h//2, block_k]``
+    bytes: the 128-wide k-block, and so the firing granularity, is the
+    q8 one, and the lane (last) dimension keeps the TPU's 128 tiling. The
+    in-register unpack is a mask/shift plus ONE concatenation along the
+    rows. ``codes`` must be int8 values in ``[-8, 7]`` whose row extent is
+    a multiple of ``block_h``; returns int8 with half the rows.
     """
-    *lead, k = codes.shape
-    if k % block_k:
-        raise ValueError(f"pack_nibbles: last dim {k} not a multiple of "
-                         f"block_k={block_k}")
-    half = block_k // 2
-    c = codes.reshape(*lead, k // block_k, 2, half)
-    lo = c[..., 0, :].astype(jnp.int32) & 15
-    hi = c[..., 1, :].astype(jnp.int32) & 15
-    return (lo | (hi << 4)).astype(jnp.int8).reshape(*lead, k // 2)
+    *lead, rows, k = codes.shape
+    if rows % block_h or block_h % 2:
+        raise ValueError(f"pack_nibbles: row dim {rows} not a multiple of "
+                         f"an even block_h={block_h}")
+    half = block_h // 2
+    c = codes.reshape(*lead, rows // block_h, 2, half, k)
+    lo = c[..., 0, :, :].astype(jnp.int32) & 15
+    hi = c[..., 1, :, :].astype(jnp.int32) & 15
+    return (lo | (hi << 4)).astype(jnp.int8).reshape(*lead, rows // 2, k)
 
 
-def unpack_nibbles(packed: Array, block_k: int) -> Array:
+def unpack_nibbles(packed: Array, block_h: int) -> Array:
     """Inverse of :func:`pack_nibbles` (sign-extended via the xor-sub
     trick: ``((n & 15) ^ 8) - 8`` maps the 4-bit two's-complement pattern
     back to ``[-8, 7]``)."""
-    *lead, kh = packed.shape
-    half = block_k // 2
-    p = packed.reshape(*lead, kh // half, half).astype(jnp.int32)
+    *lead, rh, k = packed.shape
+    half = block_h // 2
+    p = packed.reshape(*lead, rh // half, half, k).astype(jnp.int32)
     lo = ((p & 15) ^ 8) - 8
     hi = (((p >> 4) & 15) ^ 8) - 8
-    return jnp.concatenate([lo, hi], axis=-1).reshape(
-        *lead, 2 * kh).astype(jnp.int8)
+    return jnp.concatenate([lo, hi], axis=-2).reshape(
+        *lead, 2 * rh, k).astype(jnp.int8)
 
 
 def _kernel_unpack_nibbles(w):
-    """In-register unpack of ONE packed k-block inside a kernel body:
-    ``[..., block_k//2]`` int8 bytes -> ``[..., block_k]`` fp32 codes.
-    Valid because every kernel block of the packed volume is exactly one
-    k-block (see :func:`pack_nibbles`): low nibbles are the block's first
-    half-columns, high nibbles the second, so the unpack is one concat."""
+    """In-register unpack of ONE packed weight block inside a kernel body:
+    ``[G, block_h//2, block_k]`` int8 bytes -> ``[G, block_h, block_k]``
+    fp32 codes. Valid because every kernel block of the packed volume is
+    exactly one row block (see :func:`pack_nibbles`): low nibbles are the
+    block's first half-rows, high nibbles the second, so the unpack is one
+    concat along the rows."""
     p = w.astype(jnp.int32)
     lo = ((p & 15) ^ 8) - 8
     hi = (((p >> 4) & 15) ^ 8) - 8
-    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
+    return jnp.concatenate([lo, hi], axis=-2).astype(jnp.float32)
+
+
+def _code_dot(d, w):
+    """``[B, BK] x [G, BH, BK] -> [B, G, BH]`` at full fp32 precision.
+
+    Every fused kernel's block product. On a TPU the default f32 matmul
+    rounds its operands to bf16, which would round the Q8.8 deltas (up to
+    16 significant bits) and break the exact code-domain accumulation of
+    the q8/q4 kernels, and move the fp32 kernels off ``dense``;
+    ``HIGHEST`` keeps them whole. Off the TPU it changes nothing."""
+    return jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _code_einsum(d, w):
+    """The jnp oracles' ``[B, K] x [G, H, K] -> [B, G, H]`` product, at the
+    same full precision as :func:`_code_dot`."""
+    return jnp.einsum("bi,ghi->bgh", d, w,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _grid_round(v, scale: float, vmin: float, vmax: float):
@@ -239,13 +262,14 @@ class QuantDeltaLayout(_GruBlockGeometry):
 
     ``weight_bits`` (static, 8 or 4) declares the streamed code width:
     at 8, ``w_q`` is ``[gates, Hp, Ip+Hk]`` int8 codes in ``[-127, 127]``;
-    at 4 it is the nibble-packed ``[gates, Hp, (Ip+Hk)//2]`` volume
-    (two codes in ``[-7, 7]`` per byte, :func:`pack_nibbles`) — the only
+    at 4 it is the nibble-packed ``[gates, Hp//2, Ip+Hk]`` volume
+    (two codes in ``[-7, 7]`` per byte, rows paired within each
+    ``block_h`` block, :func:`pack_nibbles`) — the only
     weight-sized HBM operand then streams half the q8 bytes per fired
     column, and the kernels unpack in-register.
     """
 
-    w_q: Array                  # int8 [gates, Hp, Ip+Hk] (q4: [.., //2])
+    w_q: Array                  # int8 [gates, Hp, Ip+Hk] (q4: Hp//2 rows)
     scales: Array               # f32  [gates, Hp]
     b4: Array                   # f32  [4, Hp] (activation-grid bias)
     input_size: int
@@ -315,7 +339,7 @@ def pack_delta_weights_q8(w_x: Array, w_h: Array, b: Array | None = None,
     drops the ``-8`` pattern to stay symmetric, exactly like int8 drops
     ``-128``). Rows that are entirely zero (including Hp padding rows)
     get scale ``1/qmax`` and all-zero codes. At ``weight_bits=4`` the
-    stored ``w_q`` is the nibble-packed half-width volume
+    stored ``w_q`` is the nibble-packed half-height volume
     (:func:`pack_nibbles`).
 
     The bias rows are quantized onto the activation grid and expanded to
@@ -349,7 +373,7 @@ def pack_delta_weights_q8(w_x: Array, w_h: Array, b: Array | None = None,
     if weight_bits == 8:
         w_q = codes.astype(jnp.int8)
     else:
-        w_q = pack_nibbles(codes.astype(jnp.int8), block_k)
+        w_q = pack_nibbles(codes.astype(jnp.int8), block_h)
 
     act_scale = float(2 ** act_frac_bits)
     act_min = -float(2 ** act_int_bits)
@@ -364,7 +388,8 @@ def pack_delta_weights_q8(w_x: Array, w_h: Array, b: Array | None = None,
         bg = jnp.clip(jnp.round(bg * act_scale) / act_scale, act_min, act_max)
         b4 = jnp.pad(bg, ((0, N_MEM - gates), (0, hp - h_dim)))
     if with_ref_codes is None:
-        with_ref_codes = jax.default_backend() != "tpu"
+        from repro.kernels.ops import _interpret_default  # ops imports us
+        with_ref_codes = _interpret_default()
     return QuantDeltaLayout(
         w_q=w_q, scales=scales, b4=b4, input_size=i_dim, hidden_size=h_dim,
         block_h=block_h, block_k=block_k,
@@ -387,7 +412,7 @@ def _layout_codes_f32(layout: QuantDeltaLayout) -> Array:
     if layout.w_codes_f32 is not None:
         return layout.w_codes_f32
     if layout.weight_bits == 4:
-        return unpack_nibbles(layout.w_q, layout.block_k).astype(jnp.float32)
+        return unpack_nibbles(layout.w_q, layout.block_h).astype(jnp.float32)
     return layout.w_q.astype(jnp.float32)
 
 
@@ -415,8 +440,8 @@ def _q8_gru_kernel(n_active_ref, active_ids_ref, d_ref, w_ref, s_ref, b_ref,
     are widened to fp32 in-register and the raw ``delta x code`` products
     accumulate *unscaled* (the PE's integer accumulator — every addition
     is exact for on-grid deltas). At ``weight_bits=4`` each weight block
-    is one nibble-packed k-block (``block_k//2`` bytes) unpacked
-    in-register before the dot. The candidate gate's partials route to
+    is one nibble-packed row block (``block_h//2`` rows of bytes)
+    unpacked in-register before the dot. The candidate gate's partials route to
     ``M_xc`` / ``M_hc`` on the x/h seam. The final k-step dequantizes
     (``b + scale * acc``) and runs the Fig. 7 pipeline on the Q8.8-input /
     Q1.n-output LUT grids, rounding the new ``h`` back onto Q8.8.
@@ -434,8 +459,7 @@ def _q8_gru_kernel(n_active_ref, active_ids_ref, d_ref, w_ref, s_ref, b_ref,
             w = _kernel_unpack_nibbles(w_ref[...])   # nibbles -> f32 codes
         else:
             w = w_ref[...].astype(jnp.float32)       # int8 codes -> f32
-        p = jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        p = _code_dot(d, w)
         is_x = active_ids_ref[i] < nbk_x
         acc_ref[:, 0, :] += p[:, 0, :]               # M_r codes
         acc_ref[:, 1, :] += p[:, 1, :]               # M_u codes
@@ -485,11 +509,9 @@ def _fused_q8_step(w_q: Array, scales: Array, b4: Array, m_prev: Array,
     b = dx.shape[0]
     h_dim, hp = hidden_size, lay.hp
     nbk = lay.nbk
-    # packed q4 k-blocks are half-width in bytes; the block index map is
-    # identical (BlockSpec indices count blocks, not elements). NB the q4
-    # lane extent is block_k//2 = 64 < the 128-lane tile — fine for the
-    # interpreter and jnp path; a TPU build pads the lane dim internally.
-    wbk = block_k // 2 if weight_bits == 4 else block_k
+    # a packed q4 row block is half as tall in bytes; the block index map
+    # is identical (BlockSpec indices count blocks, not elements)
+    wbh = block_h // 2 if weight_bits == 4 else block_h
     d_cat, m4, hprev, n_active, active_ids = _prep_step_operands(
         lay, m_prev, h_prev, dx, dh)
 
@@ -499,7 +521,7 @@ def _fused_q8_step(w_q: Array, scales: Array, b4: Array, m_prev: Array,
         in_specs=[
             pl.BlockSpec((b, block_k),
                          lambda o, i, n, ids: (0, ids[i])),        # d_cat
-            pl.BlockSpec((3, block_h, wbk),
+            pl.BlockSpec((3, wbh, block_k),
                          lambda o, i, n, ids: (0, o, ids[i])),     # w_q packed
             pl.BlockSpec((3, block_h),
                          lambda o, i, n, ids: (0, o)),             # scales
@@ -533,13 +555,14 @@ def _fused_q8_step(w_q: Array, scales: Array, b4: Array, m_prev: Array,
 
 
 def deltagru_q8_step(layout: QuantDeltaLayout, m_prev: Array, h_prev: Array,
-                     dx: Array, dh: Array, *, interpret: bool = True,
+                     dx: Array, dh: Array, *, interpret: bool | None = None,
                      buffered: bool = False):
     """Public int8/int4 GRU single-step entry on encoded deltas (see
     :func:`_fused_q8_step`; ``buffered=True`` runs the double-buffered
     weight-streaming variant :func:`_fused_q8_step_dbuf` — bit-identical
     output, weights DMA'd from HBM with a two-slot overlap)."""
     step = _fused_q8_step_dbuf if buffered else _fused_q8_step
+    from repro.kernels.ops import _interpret_default  # ops imports us
     return step(layout.w_q, layout.scales, layout.b4, m_prev,
                 h_prev, dx, dh, input_size=layout.input_size,
                 hidden_size=layout.hidden_size,
@@ -547,7 +570,8 @@ def deltagru_q8_step(layout: QuantDeltaLayout, m_prev: Array, h_prev: Array,
                 act_scale=layout.act_scale, act_min=layout.act_min,
                 act_max=layout.act_max, lut_scale=layout.lut_scale,
                 lut_min=layout.lut_min, lut_max=layout.lut_max,
-                weight_bits=layout.weight_bits, interpret=interpret)
+                weight_bits=layout.weight_bits,
+                interpret=_interpret_default(interpret))
 
 
 def deltagru_q8_step_ref(layout: QuantDeltaLayout, m_prev: Array,
@@ -563,8 +587,8 @@ def deltagru_q8_step_ref(layout: QuantDeltaLayout, m_prev: Array,
     b = dx.shape[0]
     h_dim = layout.hidden_size
     cx, ch = _ref_code_slices(layout)
-    px = jnp.einsum("bi,ghi->bgh", dx.astype(jnp.float32), cx)
-    ph = jnp.einsum("bi,ghi->bgh", dh.astype(jnp.float32), ch)
+    px = _code_einsum(dx.astype(jnp.float32), cx)
+    ph = _code_einsum(dh.astype(jnp.float32), ch)
     m = m_prev.reshape(b, 4, h_dim).astype(jnp.float32)
     m_r = m[:, 0] + (px[:, 0] + ph[:, 0])
     m_u = m[:, 1] + (px[:, 1] + ph[:, 1])
@@ -605,7 +629,7 @@ def _q8_gru_kernel_dbuf(n_active_ref, active_ids_ref, d_ref, w_hbm, s_ref,
     """One o-block of the double-buffered int8/int4 fused GRU layer step.
 
     The weight volume stays in HBM (``memory_space=ANY``, pre-tiled to
-    ``[nbo, nbk, 3, block_h, wbk]`` so one fired block is one leading
+    ``[nbo, nbk, 3, wbh, block_k]`` so one fired block is one leading
     index); the kernel overlaps the DMA for fired block ``j+1`` with the
     accumulation of block ``j`` through the two-slot VMEM scratch
     ``wbuf`` and the DMA-semaphore pair ``sem`` — the EdgeDRNN fetch
@@ -639,8 +663,7 @@ def _q8_gru_kernel_dbuf(n_active_ref, active_ids_ref, d_ref, w_hbm, s_ref,
             else:
                 w = wbuf[slot].astype(jnp.float32)
             d = d_ref[j]                             # fired delta block j
-            p = jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+            p = _code_dot(d, w)
             is_x = active_ids_ref[j] < nbk_x
             acc_ref[:, 0, :] += p[:, 0, :]
             acc_ref[:, 1, :] += p[:, 1, :]
@@ -687,8 +710,8 @@ def _fused_q8_step_dbuf(w_q: Array, scales: Array, b4: Array, m_prev: Array,
     can be issued while block ``j`` accumulates. The fired delta blocks
     are pre-gathered (activation-sized, the Delta Unit's job) with the
     block index leading, so the loop indexes VMEM on the leading dim
-    only; the weight volume is re-tiled to ``[nbo, nbk, 3, block_h,
-    wbk]`` so one fired block is one leading DMA index (loop-invariant —
+    only; the weight volume is re-tiled to ``[nbo, nbk, 3, wbh,
+    block_k]`` so one fired block is one leading DMA index (loop-invariant —
     XLA hoists it out of `lax.scan` sequence bodies).
     """
     lay = QuantDeltaLayout(w_q, scales, b4, input_size, hidden_size, block_h,
@@ -697,13 +720,13 @@ def _fused_q8_step_dbuf(w_q: Array, scales: Array, b4: Array, m_prev: Array,
     b = dx.shape[0]
     h_dim, hp = hidden_size, lay.hp
     nbk = lay.nbk
-    wbk = block_k // 2 if weight_bits == 4 else block_k
+    wbh = block_h // 2 if weight_bits == 4 else block_h
     d_cat, m4, hprev, n_active, active_ids = _prep_step_operands(
         lay, m_prev, h_prev, dx, dh)
     d_act = jnp.take(d_cat.reshape(b, nbk, block_k), active_ids,
                      axis=1).transpose(1, 0, 2)                # [nbk, B, BK]
-    w_stream = w_q.reshape(3, lay.nbo, block_h, nbk,
-                           wbk).transpose(1, 3, 0, 2, 4)
+    w_stream = w_q.reshape(3, lay.nbo, wbh, nbk,
+                           block_k).transpose(1, 3, 0, 2, 4)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -711,7 +734,7 @@ def _fused_q8_step_dbuf(w_q: Array, scales: Array, b4: Array, m_prev: Array,
         in_specs=[
             pl.BlockSpec((nbk, b, block_k),
                          lambda o, n, ids: (0, 0, 0)),         # d_act
-            pl.BlockSpec(memory_space=pltpu.ANY),              # w_stream HBM
+            pl.BlockSpec(memory_space=pl.ANY),                # w_stream HBM
             pl.BlockSpec((3, block_h), lambda o, n, ids: (0, o)),   # scales
             pl.BlockSpec((4, block_h), lambda o, n, ids: (0, o)),   # b4
             pl.BlockSpec((b, 4, block_h),
@@ -722,7 +745,7 @@ def _fused_q8_step_dbuf(w_q: Array, scales: Array, b4: Array, m_prev: Array,
             pl.BlockSpec((b, 4, block_h), lambda o, n, ids: (0, 0, o)),
             pl.BlockSpec((b, block_h), lambda o, n, ids: (0, o)),
         ],
-        scratch_shapes=[pltpu.VMEM((2, 3, block_h, wbk), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((2, 3, wbh, block_k), jnp.int8),
                         pltpu.VMEM((b, 4, block_h), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
     )
@@ -775,8 +798,7 @@ def _q8_lstm_kernel(n_active_ref, active_ids_ref, d_ref, w_ref, s_ref, b_ref,
             w = _kernel_unpack_nibbles(w_ref[...])   # nibbles -> f32 codes
         else:
             w = w_ref[...].astype(jnp.float32)       # int8 codes -> f32
-        acc_ref[...] += jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
-                                            preferred_element_type=jnp.float32)
+        acc_ref[...] += _code_dot(d, w)
 
     @pl.when(i == nbk - 1)
     def _activate():
@@ -823,7 +845,7 @@ def _fused_q8_lstm_step(w_q: Array, scales: Array, b4: Array, m_prev: Array,
     b = dx.shape[0]
     h_dim, hp = hidden_size, lay.hp
     nbk = lay.nbk
-    wbk = block_k // 2 if weight_bits == 4 else block_k
+    wbh = block_h // 2 if weight_bits == 4 else block_h
     # the shared prologue also pads h_prev; the LSTM activation never
     # reads it (h = o * tanh(c)), so it is simply not handed to the kernel
     d_cat, m4, _, n_active, active_ids = _prep_step_operands(
@@ -836,7 +858,7 @@ def _fused_q8_lstm_step(w_q: Array, scales: Array, b4: Array, m_prev: Array,
         in_specs=[
             pl.BlockSpec((b, block_k),
                          lambda o, i, n, ids: (0, ids[i])),        # d_cat
-            pl.BlockSpec((4, block_h, wbk),
+            pl.BlockSpec((4, wbh, block_k),
                          lambda o, i, n, ids: (0, o, ids[i])),     # w_q packed
             pl.BlockSpec((4, block_h),
                          lambda o, i, n, ids: (0, o)),             # scales
@@ -905,9 +927,7 @@ def _q8_lstm_kernel_dbuf(n_active_ref, active_ids_ref, d_ref, w_hbm, s_ref,
             else:
                 w = wbuf[slot].astype(jnp.float32)
             d = d_ref[j]                             # fired delta block j
-            acc_ref[...] += jax.lax.dot_general(
-                d, w, (((1,), (2,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc_ref[...] += _code_dot(d, w)
             return carry
 
         jax.lax.fori_loop(0, n, body, 0)
@@ -953,14 +973,14 @@ def _fused_q8_lstm_step_dbuf(w_q: Array, scales: Array, b4: Array,
     b = dx.shape[0]
     h_dim, hp = hidden_size, lay.hp
     nbk = lay.nbk
-    wbk = block_k // 2 if weight_bits == 4 else block_k
+    wbh = block_h // 2 if weight_bits == 4 else block_h
     d_cat, m4, _, n_active, active_ids = _prep_step_operands(
         lay, m_prev, h_prev, dx, dh)
     cprev = jnp.pad(c_prev, ((0, 0), (0, hp - h_dim)))
     d_act = jnp.take(d_cat.reshape(b, nbk, block_k), active_ids,
                      axis=1).transpose(1, 0, 2)                # [nbk, B, BK]
-    w_stream = w_q.reshape(4, lay.nbo, block_h, nbk,
-                           wbk).transpose(1, 3, 0, 2, 4)
+    w_stream = w_q.reshape(4, lay.nbo, wbh, nbk,
+                           block_k).transpose(1, 3, 0, 2, 4)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -968,7 +988,7 @@ def _fused_q8_lstm_step_dbuf(w_q: Array, scales: Array, b4: Array,
         in_specs=[
             pl.BlockSpec((nbk, b, block_k),
                          lambda o, n, ids: (0, 0, 0)),         # d_act
-            pl.BlockSpec(memory_space=pltpu.ANY),              # w_stream HBM
+            pl.BlockSpec(memory_space=pl.ANY),                # w_stream HBM
             pl.BlockSpec((4, block_h), lambda o, n, ids: (0, o)),   # scales
             pl.BlockSpec((4, block_h), lambda o, n, ids: (0, o)),   # b4
             pl.BlockSpec((b, 4, block_h),
@@ -980,7 +1000,7 @@ def _fused_q8_lstm_step_dbuf(w_q: Array, scales: Array, b4: Array,
             pl.BlockSpec((b, block_h), lambda o, n, ids: (0, o)),
             pl.BlockSpec((b, block_h), lambda o, n, ids: (0, o)),
         ],
-        scratch_shapes=[pltpu.VMEM((2, 4, block_h, wbk), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((2, 4, wbh, block_k), jnp.int8),
                         pltpu.VMEM((b, 4, block_h), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
     )
@@ -1003,11 +1023,12 @@ def _fused_q8_lstm_step_dbuf(w_q: Array, scales: Array, b4: Array,
 
 def deltalstm_q8_step(layout: QuantDeltaLayout, m_prev: Array, h_prev: Array,
                       c_prev: Array, dx: Array, dh: Array, *,
-                      interpret: bool = True, buffered: bool = False):
+                      interpret: bool | None = None, buffered: bool = False):
     """Public int8/int4 LSTM single-step entry on encoded deltas (see
     :func:`_fused_q8_lstm_step`; ``buffered=True`` runs the
     double-buffered weight-streaming variant — bit-identical output)."""
     step = _fused_q8_lstm_step_dbuf if buffered else _fused_q8_lstm_step
+    from repro.kernels.ops import _interpret_default  # ops imports us
     return step(
         layout.w_q, layout.scales, layout.b4, m_prev, h_prev, c_prev, dx, dh,
         input_size=layout.input_size, hidden_size=layout.hidden_size,
@@ -1015,7 +1036,8 @@ def deltalstm_q8_step(layout: QuantDeltaLayout, m_prev: Array, h_prev: Array,
         act_scale=layout.act_scale, act_min=layout.act_min,
         act_max=layout.act_max, lut_scale=layout.lut_scale,
         lut_min=layout.lut_min, lut_max=layout.lut_max,
-        weight_bits=layout.weight_bits, interpret=interpret)
+        weight_bits=layout.weight_bits,
+        interpret=_interpret_default(interpret))
 
 
 def deltalstm_q8_step_ref(layout: QuantDeltaLayout, m_prev: Array,
@@ -1030,8 +1052,8 @@ def deltalstm_q8_step_ref(layout: QuantDeltaLayout, m_prev: Array,
     b = dx.shape[0]
     h_dim = layout.hidden_size
     cx, ch = _ref_code_slices(layout)
-    px = jnp.einsum("bi,ghi->bgh", dx.astype(jnp.float32), cx)
-    ph = jnp.einsum("bi,ghi->bgh", dh.astype(jnp.float32), ch)
+    px = _code_einsum(dx.astype(jnp.float32), cx)
+    ph = _code_einsum(dh.astype(jnp.float32), ch)
     m = m_prev.reshape(b, 4, h_dim).astype(jnp.float32) + (px + ph)
 
     def q88(v):

@@ -65,7 +65,7 @@ def pack_spmv_weights(w: Array, block_o: int = 128,
                                              "interpret", "packed", "out_dim"))
 def delta_spmv(w: Array, dx: Array, acc: Array | None = None, *,
                block_o: int = 128, block_k: int = 128,
-               interpret: bool = True, packed: bool = False,
+               interpret: bool | None = None, packed: bool = False,
                out_dim: int | None = None) -> Array:
     """``acc + dx @ w.T`` with fired-block-only weight fetch.
 
@@ -75,12 +75,15 @@ def delta_spmv(w: Array, dx: Array, acc: Array | None = None, *,
       dx: ``[B, I]`` delta vectors (zeros = not fired).
       acc: ``[B, O]`` accumulator (delta memory M); zeros if None.
       block_o/block_k: VMEM tile sizes (128-aligned for MXU).
-      interpret: run the Pallas body in Python (CPU container); False on TPU.
+      interpret: run the Pallas body in Python; ``None`` chooses by
+        platform (:func:`repro.kernels.ops._interpret_default`).
       packed: weights are already block-padded (skips the per-call pad).
       out_dim: true output dim O when ``packed`` (defaults to ``w.shape[0]``).
 
     Returns ``[B, O]``.
     """
+    from repro.kernels.ops import _interpret_default  # ops imports us
+    interpret = _interpret_default(interpret)
     b, i_dim = dx.shape
     o_dim = out_dim if (packed and out_dim is not None) else w.shape[0]
     if acc is None:

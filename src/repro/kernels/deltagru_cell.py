@@ -43,7 +43,7 @@ def _kernel(m_ref, zx_ref, zh_ref, h_ref, m_out_ref, h_out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_h", "interpret"))
 def deltagru_act(m_prev: Array, zx: Array, zh: Array, h_prev: Array, *,
-                 block_h: int = 128, interpret: bool = True):
+                 block_h: int = 128, interpret: bool | None = None):
     """Fused Eq. 3 pointwise update.
 
     Args:
@@ -54,6 +54,8 @@ def deltagru_act(m_prev: Array, zx: Array, zh: Array, h_prev: Array, *,
 
     Returns ``(m_new: [B, 4H], h_new: [B, H])``.
     """
+    from repro.kernels.ops import _interpret_default  # ops imports us
+    interpret = _interpret_default(interpret)
     b, four_h = m_prev.shape
     h_dim = four_h // 4
     h_pad = (-h_dim) % block_h

@@ -54,7 +54,8 @@ from jax.experimental.pallas import tpu as pltpu
 # re-exported here for compatibility — new code should import them from
 # repro.kernels.delta_q8 directly.
 from repro.kernels.delta_q8 import (  # noqa: F401  (re-exports)
-    QuantDeltaLayout, _grid_round, _GruBlockGeometry, _prep_step_operands,
+    QuantDeltaLayout, _code_dot, _code_einsum, _grid_round,
+    _GruBlockGeometry, _prep_step_operands,
     deltagru_q8_step, deltagru_q8_step_ref, pack_cat_volume,
     pack_delta_weights_q4, pack_delta_weights_q8, pack_nibbles,
     unpack_nibbles)
@@ -126,8 +127,7 @@ def _kernel(n_active_ref, active_ids_ref, d_ref, w_ref, m_ref, h_ref,
     def _accumulate():
         d = d_ref[...]                               # [B, BK]
         w = w_ref[...]                               # [3, BH, BK]
-        p = jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        p = _code_dot(d, w)
         is_x = active_ids_ref[i] < nbk_x             # block left of the seam?
         acc_ref[:, 0, :] += p[:, 0, :]               # M_r: both streams
         acc_ref[:, 1, :] += p[:, 1, :]               # M_u: both streams
@@ -196,13 +196,15 @@ def _fused_step(w: Array, m_prev: Array, h_prev: Array, dx: Array, dh: Array,
 
 
 def deltagru_seq_step(layout: FusedGruLayout, m_prev: Array, h_prev: Array,
-                      dx: Array, dh: Array, *, interpret: bool = True):
+                      dx: Array, dh: Array, *,
+                      interpret: bool | None = None):
     """Public single-step entry on encoded deltas (see :func:`_fused_step`)."""
+    from repro.kernels.ops import _interpret_default  # ops imports us
     return _fused_step(layout.w, m_prev, h_prev, dx, dh,
                        input_size=layout.input_size,
                        hidden_size=layout.hidden_size,
                        block_h=layout.block_h, block_k=layout.block_k,
-                       interpret=interpret)
+                       interpret=_interpret_default(interpret))
 
 
 def deltagru_seq_step_ref(layout: FusedGruLayout, m_prev: Array,
@@ -213,8 +215,8 @@ def deltagru_seq_step_ref(layout: FusedGruLayout, m_prev: Array,
     w = layout.w.astype(jnp.float32)
     wx = w[:, :h_dim, :layout.input_size]            # [3, H, I]
     wh = w[:, :h_dim, layout.ip:layout.ip + h_dim]   # [3, H, H]
-    px = jnp.einsum("bi,ghi->bgh", dx.astype(jnp.float32), wx)
-    ph = jnp.einsum("bi,ghi->bgh", dh.astype(jnp.float32), wh)
+    px = _code_einsum(dx.astype(jnp.float32), wx)
+    ph = _code_einsum(dh.astype(jnp.float32), wh)
     m = m_prev.reshape(b, 4, h_dim).astype(jnp.float32)
     m_r = m[:, 0] + px[:, 0] + ph[:, 0]
     m_u = m[:, 1] + px[:, 1] + ph[:, 1]

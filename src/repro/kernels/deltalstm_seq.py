@@ -46,7 +46,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.delta_q8 import (  # noqa: F401  (re-exports)
-    QuantDeltaLayout, _GruBlockGeometry, _prep_step_operands,
+    QuantDeltaLayout, _code_dot, _code_einsum, _GruBlockGeometry,
+    _prep_step_operands,
     deltalstm_q8_step, deltalstm_q8_step_ref, pack_cat_volume,
     pack_delta_weights_q4, pack_delta_weights_q8, pack_nibbles,
     unpack_nibbles)
@@ -136,8 +137,7 @@ def _lstm_kernel(n_active_ref, active_ids_ref, d_ref, w_ref, m_ref, c_ref,
     def _accumulate():
         d = d_ref[...]                               # [B, BK]
         w = w_ref[...]                               # [4, BH, BK]
-        p = jax.lax.dot_general(d, w, (((1,), (2,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        p = _code_dot(d, w)
         acc_ref[...] += p                            # M_i, M_f, M_g, M_o
 
     @pl.when(i == nbk - 1)
@@ -213,14 +213,15 @@ def _fused_lstm_step(w: Array, m_prev: Array, h_prev: Array, c_prev: Array,
 
 def deltalstm_seq_step(layout: FusedLstmLayout, m_prev: Array, h_prev: Array,
                        c_prev: Array, dx: Array, dh: Array, *,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Public single-step entry on encoded deltas (see
     :func:`_fused_lstm_step`)."""
+    from repro.kernels.ops import _interpret_default  # ops imports us
     return _fused_lstm_step(layout.w, m_prev, h_prev, c_prev, dx, dh,
                             input_size=layout.input_size,
                             hidden_size=layout.hidden_size,
                             block_h=layout.block_h, block_k=layout.block_k,
-                            interpret=interpret)
+                            interpret=_interpret_default(interpret))
 
 
 def deltalstm_seq_step_ref(layout: FusedLstmLayout, m_prev: Array,
@@ -232,8 +233,8 @@ def deltalstm_seq_step_ref(layout: FusedLstmLayout, m_prev: Array,
     w = layout.w.astype(jnp.float32)
     wx = w[:, :h_dim, :layout.input_size]            # [4, H, I]
     wh = w[:, :h_dim, layout.ip:layout.ip + h_dim]   # [4, H, H]
-    px = jnp.einsum("bi,ghi->bgh", dx.astype(jnp.float32), wx)
-    ph = jnp.einsum("bi,ghi->bgh", dh.astype(jnp.float32), wh)
+    px = _code_einsum(dx.astype(jnp.float32), wx)
+    ph = _code_einsum(dh.astype(jnp.float32), wh)
     m = m_prev.reshape(b, 4, h_dim).astype(jnp.float32) + px + ph
     gi = jax.nn.sigmoid(m[:, 0])
     gf = jax.nn.sigmoid(m[:, 1])

@@ -37,7 +37,13 @@ def set_force_ref(value: bool):
     _FORCE_REF = value
 
 
-def _interpret_default() -> bool:
+def _interpret_default(interpret: bool | None = None) -> bool:
+    """The one place the Pallas mode is chosen: ``interpret`` as given, or
+    for ``None`` compiled kernels on a TPU and the interpreter everywhere
+    else. Every kernel entry point takes ``interpret=None`` and resolves
+    it here."""
+    if interpret is not None:
+        return interpret
     return jax.default_backend() != "tpu"
 
 
@@ -56,7 +62,6 @@ def delta_spmv(w: Array, dx: Array, acc: Array | None = None, *,
             w = w[:out_dim if out_dim is not None else w.shape[0],
                   :dx.shape[-1]]
         return _ref.delta_spmv_ref(w, dx, acc, block_k=block_k)
-    interpret = _interpret_default() if interpret is None else interpret
     return _delta_spmv_pallas(w, dx, acc, block_o=block_o, block_k=block_k,
                               interpret=interpret, packed=packed,
                               out_dim=out_dim)
@@ -68,7 +73,6 @@ def deltagru_act(m_prev: Array, zx: Array, zh: Array, h_prev: Array, *,
     """Fused DeltaGRU pointwise pipeline (paper Fig. 7)."""
     if use_ref or _FORCE_REF:
         return _ref.deltagru_act_ref(m_prev, zx, zh, h_prev)
-    interpret = _interpret_default() if interpret is None else interpret
     return _deltagru_act_pallas(m_prev, zx, zh, h_prev, block_h=block_h,
                                 interpret=interpret)
 
@@ -79,7 +83,6 @@ def rwkv6_scan(r: Array, k: Array, v: Array, w: Array, u: Array,
     """WKV6 linear-attention recurrence over ``[B, H, T, D]``."""
     if use_ref or _FORCE_REF:
         return _ref.rwkv6_scan_batched_ref(r, k, v, w, u, s0)
-    interpret = _interpret_default() if interpret is None else interpret
     return _rwkv6_scan_pallas(r, k, v, w, u, s0, chunk=chunk,
                               interpret=interpret)
 
@@ -108,7 +111,6 @@ def rglru_scan(x: Array, a: Array, h0: Array | None = None, *,
     """RG-LRU diagonal recurrence over ``[B, T, D]``."""
     if use_ref or _FORCE_REF:
         return _ref.rglru_scan_batched_ref(x, a, h0)
-    interpret = _interpret_default() if interpret is None else interpret
     return _rglru_scan_pallas(x, a, h0, chunk=chunk, block_d=block_d,
                               interpret=interpret)
 

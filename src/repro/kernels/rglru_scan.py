@@ -51,8 +51,8 @@ def rglru_scan(x: Array, a: Array, h0: Array | None = None, *,
     interpret-mode emulation elsewhere — a real device never silently runs
     the interpreter unless explicitly asked to (``interpret=True``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels.ops import _interpret_default  # ops imports us
+    interpret = _interpret_default(interpret)
     b, t, d = x.shape
     if h0 is None:
         h0 = jnp.zeros((b, d), jnp.float32)

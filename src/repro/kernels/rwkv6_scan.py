@@ -64,8 +64,8 @@ def rwkv6_scan(r: Array, k: Array, v: Array, w: Array, u: Array,
     interpret-mode emulation elsewhere — a real device never silently runs
     the interpreter unless explicitly asked to (``interpret=True``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels.ops import _interpret_default  # ops imports us
+    interpret = _interpret_default(interpret)
     b, h, t, d = r.shape
     if s0 is None:
         s0 = jnp.zeros((b, h, d, d), jnp.float32)
@@ -76,7 +76,8 @@ def rwkv6_scan(r: Array, k: Array, v: Array, w: Array, u: Array,
         w = jnp.pad(w, pad, constant_values=1.0)  # identity decay on padding
     tp = t + t_pad
     bh = b * h
-    u_bh = jnp.tile(u.astype(jnp.float32), (b, 1))  # [B*H, D]
+    # [B*H, 1, D]: a (1, D) block spans the last two dims whole
+    u_bh = jnp.tile(u.astype(jnp.float32), (b, 1)).reshape(bh, 1, d)
 
     def flat(x):
         return x.reshape(bh, 1, tp, d)
@@ -89,7 +90,7 @@ def rwkv6_scan(r: Array, k: Array, v: Array, w: Array, u: Array,
             pl.BlockSpec((1, 1, chunk, d), lambda bh_, tc: (bh_, 0, tc, 0)),  # k
             pl.BlockSpec((1, 1, chunk, d), lambda bh_, tc: (bh_, 0, tc, 0)),  # v
             pl.BlockSpec((1, 1, chunk, d), lambda bh_, tc: (bh_, 0, tc, 0)),  # w
-            pl.BlockSpec((1, d), lambda bh_, tc: (bh_, 0)),                   # u
+            pl.BlockSpec((1, 1, d), lambda bh_, tc: (bh_, 0, 0)),             # u
             pl.BlockSpec((1, 1, d, d), lambda bh_, tc: (bh_, 0, 0, 0)),       # s0
         ],
         out_specs=[

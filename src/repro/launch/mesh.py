@@ -8,12 +8,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.dist.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The assigned production mesh: 16x16 per pod, 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
@@ -21,4 +23,4 @@ def make_debug_mesh(data: int = 2, model: int = 2):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -23,7 +23,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_mesh, current_rules
@@ -123,11 +122,11 @@ def moe_apply_ep(params, x: Array, *, top_k: int,
         y = jax.lax.psum(y, ep_name)          # sum expert partials (TP-style)
         return y.reshape(b, s, d), aux[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(ep_name, None, None), P(ep_name, None, None),
                   P(ep_name, None, None), P(dp_axes, None, None)),
         out_specs=(P(dp_axes, None, None), P(dp_axes)),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(params["router"], wg_full, wu_full, wd_full, x)
     return y, jnp.mean(aux)

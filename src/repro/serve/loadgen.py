@@ -20,7 +20,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
-__all__ = ["poisson_arrivals", "run_fabric_load", "LoadRunSummary"]
+__all__ = ["poisson_arrivals", "run_fabric_load", "fabric_parity",
+           "LoadRunSummary"]
 
 
 def poisson_arrivals(n_streams: int, rate_per_tick: float, *,
@@ -108,3 +109,40 @@ def run_fabric_load(router, arrivals, *, scale_down_at: int | None = None,
                           peak_concurrent_full=peak_full,
                           peak_active=peak_active, ticks=router.tick_no,
                           scale_info=scale_info)
+
+
+def fabric_parity(arrivals, results, fleet) -> int:
+    """Bitwise-compare every completed stream of a fabric load run
+    (``results`` from :func:`run_fabric_load`) against a clean standalone
+    engine at the fleet's per-shard tile width; returns how many matched
+    and raises ``AssertionError`` on the first that does not.
+
+    Up to one tile width of streams shares each reference run: at a fixed
+    tile width, companion streams are bitwise-neutral, so one
+    ``step_many`` checks B streams."""
+    b = fleet.streams_per_shard
+    i_dim = fleet.dims.input_size
+    ref = fleet.reference_engine()
+    completed = [(i, r) for i, r in sorted(results.items())
+                 if r.status == "ok"]
+    parity_ok = 0
+    for base in range(0, len(completed), b):
+        group = completed[base:base + b]
+        t_max = max(len(arrivals[i][1]) for i, _ in group)
+        xs = np.zeros((t_max, b, i_dim), np.float32)
+        for j, (i, _) in enumerate(group):
+            frames = arrivals[i][1]
+            xs[:len(frames), j] = frames
+            # pad with the last frame: zero delta, and causality means the
+            # real prefix's outputs are unaffected
+            xs[len(frames):, j] = frames[-1]
+        ref.reset()
+        want = np.asarray(ref.step_many(xs))
+        for j, (i, r) in enumerate(group):
+            got = np.stack([np.asarray(o) for o in r.outputs])
+            assert want[:len(got), j].tobytes() == got.tobytes(), \
+                f"fabric parity: arrival {i} (shard {r.shard}, " \
+                f"replayed={r.replayed}, {len(got)} frames) diverged " \
+                "from its clean same-width reference"
+            parity_ok += 1
+    return parity_ok

@@ -14,7 +14,7 @@ from repro.dist.elastic import best_mesh, scale_event
 from repro.dist.grad_compress import (CompressionConfig, compress,
                                       init_residual)
 from repro.dist.sharding import (AxisRules, enforce_divisibility,
-                                 infer_param_specs, use_mesh)
+                                 infer_param_specs, make_mesh, use_mesh)
 from repro.ft import checkpoint as ckpt
 from repro.ft.heartbeat import HeartbeatMonitor
 from repro.ft.straggler import StragglerDetector
@@ -22,14 +22,14 @@ from repro.ft.straggler import StragglerDetector
 
 class TestShardingRules:
     def test_resolve_drops_missing_axes(self):
-        mesh = jax.make_mesh((max(len(jax.devices()), 1),), ("data",))
+        mesh = make_mesh((max(len(jax.devices()), 1),), ("data",))
         rules = AxisRules()
         spec = rules.resolve("batch", "heads", mesh=mesh)
         assert spec == P("data", None)  # pod/model absent -> dropped
 
     def test_enforce_divisibility(self):
         n = len(jax.devices())
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         spec = enforce_divisibility(P("data"), (n * 3,), mesh)
         assert spec == P("data")
         spec = enforce_divisibility(P("data"), (n * 3 + 1,), mesh)
@@ -37,8 +37,8 @@ class TestShardingRules:
 
     def test_param_rules_match_paths(self):
         n = len(jax.devices())
-        mesh = jax.make_mesh((n // 2, 2) if n >= 2 else (1, 1),
-                             ("data", "model"))
+        mesh = make_mesh((n // 2, 2) if n >= 2 else (1, 1),
+                         ("data", "model"))
         params = {"blocks": {"attn": {"w_q": jnp.zeros((8, 16))},
                              "ffn": {"w_down": jnp.zeros((16, 8))}},
                   "embedding": jnp.zeros((32, 8))}
@@ -88,7 +88,7 @@ class TestPipelineParallel:
             pytest.skip("needs >= 2 devices")
         from repro.dist.pipeline import pipeline_forward, split_microbatches
         stages = min(n, 4)
-        mesh = jax.make_mesh((stages,), ("stage",))
+        mesh = make_mesh((stages,), ("stage",))
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (stages, 8, 8)) * 0.3
 
@@ -156,7 +156,7 @@ class TestCheckpoint:
         n = len(jax.devices())
         state = {"w": jnp.arange(float(n * 4)).reshape(n, 4)}
         ckpt.save(str(tmp_path), 1, state)
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         restored = ckpt.restore(str(tmp_path), state, shardings=sh)
         assert restored["w"].sharding.num_devices == n
@@ -180,7 +180,7 @@ class TestCheckpoint:
         state = {"w": jnp.arange(float(n * 4)).reshape(n, 4)}  # fp32 save
         ckpt.save(str(tmp_path), 1, state)
         target = {"w": jnp.zeros((n, 4), jnp.bfloat16)}
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         sharded = ckpt.restore(str(tmp_path), target, shardings=sh)
         assert sharded["w"].dtype == jnp.bfloat16

@@ -64,11 +64,11 @@ class TestCollectives:
         n = len(jax.devices())
         if n < 2:
             pytest.skip("needs > 1 device")
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((n,), ("d",))
-        fn = shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
-                       in_specs=P("d"), out_specs=P())
+        from repro.dist.sharding import make_mesh
+        mesh = make_mesh((n,), ("d",))
+        fn = jax.shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
+                           in_specs=P("d"), out_specs=P())
         x = jax.ShapeDtypeStruct((n * 128,), jnp.float32)
         compiled = jax.jit(fn).lower(x).compile()
         r = module_costs(compiled.as_text())

@@ -5,7 +5,7 @@ fixed-point reference built from the :mod:`repro.quant` primitives and a
 TEST-LOCAL numpy nibble decoder (same Qm.n grids, same documented packing
 convention, none of the runtime's unpack code): int4 per-gate-row weight
 codes in [-7, 7], two codes per streamed byte over the
-``[gates, Hp, (Ip+Hk)//2]`` volume, Q8.8 activation grid, unscaled
+``[gates, Hp//2, Ip+Hk]`` volume, Q8.8 activation grid, unscaled
 code-domain delta memories, bias + dequant at the activation stage,
 Q8.8 -> Q1.4 LUT nonlinearities. Because the code-domain accumulation is
 exact in fp32 for on-grid deltas, every summation order gives the same
@@ -52,26 +52,31 @@ from repro.serve.scheduler import GruStreamBatcher
 LUT_Q14 = QFormat(1, 4)
 
 
-def _unpack_nibbles_np(packed, block_k):
+def _unpack_nibbles_np(packed, block_h):
     """TEST-LOCAL numpy nibble decoder, written from the documented
-    convention (not the runtime code): within each k-block of
-    ``block_k // 2`` bytes, byte ``j`` carries column ``j`` in its low
-    nibble and column ``j + block_k // 2`` in its high nibble, each a
-    4-bit two's-complement code."""
+    convention (not the runtime code): the codes are paired along the
+    rows (second-to-last axis). Within each row block of ``block_h // 2``
+    packed rows, packed row ``j`` carries row ``j`` of the block in its
+    low nibble and row ``j + block_h // 2`` in its high nibble, column by
+    column, each a 4-bit two's-complement code."""
     p = np.asarray(packed).astype(np.int32)
-    half = block_k // 2
-    *lead, kp = p.shape
-    p = p.reshape(*lead, kp // half, half)
-    lo = ((p & 15) ^ 8) - 8
-    hi = (((p >> 4) & 15) ^ 8) - 8
-    return np.stack([lo, hi], axis=-2).reshape(*lead, 2 * kp)
+    half = block_h // 2
+    *lead, rp, k = p.shape
+    out = np.empty((*lead, 2 * rp, k), np.int32)
+    for blk in range(rp // half):
+        rows = p[..., blk * half:(blk + 1) * half, :]
+        lo = ((rows & 15) ^ 8) - 8
+        hi = (((rows >> 4) & 15) ^ 8) - 8
+        out[..., 2 * blk * half:(2 * blk + 1) * half, :] = lo
+        out[..., (2 * blk + 1) * half:(2 * blk + 2) * half, :] = hi
+    return out
 
 
 def _codes_f32(lay):
     """fp32 code volume of a layout via the independent numpy decoder."""
     if lay.weight_bits == 4:
         return jnp.asarray(
-            _unpack_nibbles_np(lay.w_q, lay.block_k).astype(np.float32))
+            _unpack_nibbles_np(lay.w_q, lay.block_h).astype(np.float32))
     return lay.w_q.astype(jnp.float32)
 
 
@@ -184,36 +189,37 @@ def _fake_quant_lstm_q4_reference(layouts, xs, theta_x, theta_h):
 
 
 class TestNibblePacking:
-    @pytest.mark.parametrize("shape,block_k",
-                             [((3, 8, 16), 8), ((4, 5, 24), 4),
-                              ((32,), 32)])
-    def test_round_trip(self, shape, block_k):
-        rng = np.random.default_rng(sum(shape) + block_k)
+    @pytest.mark.parametrize("shape,block_h",
+                             [((3, 16, 8), 8), ((4, 24, 5), 4),
+                              ((32, 1), 32)])
+    def test_round_trip(self, shape, block_h):
+        rng = np.random.default_rng(sum(shape) + block_h)
         codes = rng.integers(-7, 8, size=shape).astype(np.int8)
-        packed = pack_nibbles(jnp.asarray(codes), block_k)
+        packed = pack_nibbles(jnp.asarray(codes), block_h)
         assert packed.dtype == jnp.int8
-        assert packed.shape == shape[:-1] + (shape[-1] // 2,)
+        assert packed.shape == shape[:-2] + (shape[-2] // 2, shape[-1])
         np.testing.assert_array_equal(
-            np.asarray(unpack_nibbles(packed, block_k)), codes)
+            np.asarray(unpack_nibbles(packed, block_h)), codes)
         # and the independent numpy decoder agrees — this pins the
-        # low/high nibble-to-column convention, not just invertibility
+        # low/high nibble-to-row convention, not just invertibility
         np.testing.assert_array_equal(
-            _unpack_nibbles_np(packed, block_k), codes)
+            _unpack_nibbles_np(packed, block_h), codes)
 
     def test_rejects_non_block_multiple(self):
         with pytest.raises(ValueError, match="block"):
             pack_nibbles(jnp.zeros((3, 10), jnp.int8), 4)
 
     def test_odd_raw_extent_pads_through(self):
-        """An odd raw I + H still packs: the volume is padded to block
-        multiples first, so the nibble pairing never straddles layers."""
+        """An odd raw H (the paired row extent) still packs: the rows are
+        padded to block_h multiples first, so the nibble pairing never
+        straddles row blocks."""
         p = init_gru_stack(jax.random.PRNGKey(0), 13, 17, 1)[0]
         lay = pack_delta_weights_q4(p.w_x, p.w_h, b=p.b, block_h=8,
                                     block_k=8)
-        assert (13 + 17) % 2 == 0 and (lay.ip + lay.hk) % lay.block_k == 0
+        assert 17 % 2 == 1 and lay.hp % lay.block_h == 0
         assert lay.weight_bits == 4
-        assert lay.w_q.shape == (3, lay.hp, (lay.ip + lay.hk) // 2)
-        codes = _unpack_nibbles_np(lay.w_q, lay.block_k)
+        assert lay.w_q.shape == (3, lay.hp // 2, lay.ip + lay.hk)
+        codes = _unpack_nibbles_np(lay.w_q, lay.block_h)
         assert codes.min() >= -7 and codes.max() <= 7
         # dequantized codes reproduce the int4 fake-quant view of w_x
         w = codes[:, :17, :13] * np.asarray(lay.scales)[:, :17, None]
@@ -278,8 +284,8 @@ class TestFusedQ4BitMatchGru:
         for lay in layouts:
             assert lay.weight_bits == 4
             assert lay.w_q.dtype == jnp.int8          # the HBM operand
-            assert lay.w_q.shape == (3, lay.hp, (lay.ip + lay.hk) // 2)
-            codes = _unpack_nibbles_np(lay.w_q, lay.block_k)
+            assert lay.w_q.shape == (3, lay.hp // 2, lay.ip + lay.hk)
+            codes = _unpack_nibbles_np(lay.w_q, lay.block_h)
             assert codes.min() >= -7 and codes.max() <= 7
 
 

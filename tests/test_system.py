@@ -10,6 +10,7 @@ from repro.configs.registry import get_config
 from repro.core.perf_model import estimate_stack
 from repro.core.sparsity import GruDims
 from repro.data.synthetic import batch_stream, gas_batch
+from repro.dist.sharding import make_mesh
 from repro.models.gru_rnn import GruTaskConfig, init_gru_model
 from repro.quant.qat import EDGEDRNN_QAT
 from repro.serve.engine import GruStreamEngine
@@ -83,7 +84,7 @@ class TestMoEEngines:
             pytest.skip("needs >= 4 devices")
         from repro.dist.sharding import AxisRules, use_mesh
         from repro.models.moe import init_moe, moe_apply, moe_apply_auto
-        mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+        mesh = make_mesh((n // 2, 2), ("data", "model"))
         p = init_moe(jax.random.PRNGKey(0), 16, 32, 8, pad_to=2)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
         y_ref, a_ref = moe_apply(p, x, top_k=2, capacity_factor=8.0)
@@ -110,7 +111,7 @@ class TestShardedTraining:
         from repro.train.trainer import (init_train_state,
                                          make_lm_train_step_fn)
         cfg = get_config("llama3.2-1b").reduced()
-        mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+        mesh = make_mesh((n // 2, 2), ("data", "model"))
         rules = AxisRules()
         params = init_lm(jax.random.PRNGKey(0), cfg)
         state = init_train_state(params)
