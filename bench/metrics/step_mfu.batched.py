@@ -1,0 +1,7 @@
+"""``step_mfu`` in the cells that serve many streams through the batcher,
+where it moves their own median frame latency."""
+from harness.spec import load_reader
+
+_base = load_reader("step_mfu")
+LAYER, UNIT, read = _base.LAYER, _base.UNIT, _base.read
+MOVES = "frame_latency_p50_ms.batched"
