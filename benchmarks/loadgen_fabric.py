@@ -182,8 +182,8 @@ def bench_fabric_record(**cfg):
         "wall_s": wall_s,
         "streams_per_s": cons["completed"] / wall_s,
         "frames_per_s": cons["frames_out"] / wall_s,
-        "p50_tick_wall_s": _steady_percentile(router.tick_wall_s, 0.50),
-        "p99_tick_wall_s": _steady_percentile(router.tick_wall_s, 0.99),
+        "p50_tick_wall_s": _steady_percentile(summary.tick_wall_s, 0.50),
+        "p99_tick_wall_s": _steady_percentile(summary.tick_wall_s, 0.99),
     }
 
     from benchmarks.kernel_bench import record_meta

@@ -100,6 +100,9 @@ from repro.models.gru_rnn import GruTaskConfig
 from repro.models.lm import init_lm_caches, lm_decode, lm_prefill
 
 Array = jax.Array
+# Host spans of the engine tick, written into the profiler's trace (on the
+# device ops' clock) and nearly free when no profiler is collecting.
+_span = jax.profiler.TraceAnnotation
 
 
 class LmEngine:
@@ -496,24 +499,30 @@ class DeltaStreamEngine:
         arrays are immutable and skip the copy, keeping the zero-sync
         hot path.
         """
-        if isinstance(x, np.ndarray):
-            x = np.array(x, np.float32)
-        x = jnp.asarray(x, jnp.float32)
-        i_dim = self.dims.input_size
-        if x.ndim == 1 and self.n_streams == 1:
-            x = x[None]
-        if x.shape != (self.n_streams, i_dim):
-            want = (f"[{i_dim}]" if self.n_streams == 1
-                    else f"[{self.n_streams}, {i_dim}]")
-            raise ValueError(
-                f"engine has n_streams={self.n_streams}; step needs one "
-                f"frame per stream slot, shape {want}"
-                f"{f' or [1, {i_dim}]' if self.n_streams == 1 else ''}, "
-                f"got {tuple(x.shape)} — reshaping would silently "
-                "cross-contaminate stream slots")
-        out, self.state, self._carry = self._step(self.state, self._carry, x)
+        with _span("engine.h2d"):
+            if isinstance(x, np.ndarray):
+                x = np.array(x, np.float32)
+            x = jnp.asarray(x, jnp.float32)
+            i_dim = self.dims.input_size
+            if x.ndim == 1 and self.n_streams == 1:
+                x = x[None]
+            if x.shape != (self.n_streams, i_dim):
+                want = (f"[{i_dim}]" if self.n_streams == 1
+                        else f"[{self.n_streams}, {i_dim}]")
+                raise ValueError(
+                    f"engine has n_streams={self.n_streams}; step needs one "
+                    f"frame per stream slot, shape {want}"
+                    f"{f' or [1, {i_dim}]' if self.n_streams == 1 else ''}, "
+                    f"got {tuple(x.shape)} — reshaping would silently "
+                    "cross-contaminate stream slots")
+        with _span("engine.launch"):
+            out, self.state, self._carry = self._step(self.state,
+                                                      self._carry, x)
         self._n_steps += 1
-        return out[0] if self.n_streams == 1 else out
+        if self.n_streams != 1:
+            return out
+        with _span("engine.unpack"):
+            return out[0]
 
     def step_many(self, xs: np.ndarray | Array) -> Array:
         """Process a chunk of timesteps in ONE device call (``lax.scan``).
@@ -525,24 +534,26 @@ class DeltaStreamEngine:
         deferred ingestion of a caller-owned buffer races with the async
         dispatch).
         """
-        if isinstance(xs, np.ndarray):
-            xs = np.array(xs, np.float32)
-        xs = jnp.asarray(xs, jnp.float32)
-        squeeze = xs.ndim == 2
-        if squeeze:
-            if self.n_streams != 1:
+        with _span("engine.h2d"):
+            if isinstance(xs, np.ndarray):
+                xs = np.array(xs, np.float32)
+            xs = jnp.asarray(xs, jnp.float32)
+            squeeze = xs.ndim == 2
+            if squeeze:
+                if self.n_streams != 1:
+                    raise ValueError(
+                        f"engine has n_streams={self.n_streams}; step_many "
+                        f"needs [T, {self.n_streams}, I], got {xs.shape} "
+                        "(a 2-D chunk would silently broadcast one "
+                        "stream's input to all streams)")
+                xs = xs[:, None, :]
+            elif xs.shape[1] != self.n_streams:
                 raise ValueError(
-                    f"engine has n_streams={self.n_streams}; step_many "
-                    f"needs [T, {self.n_streams}, I], got {xs.shape} "
-                    "(a 2-D chunk would silently broadcast one stream's "
-                    "input to all streams)")
-            xs = xs[:, None, :]
-        elif xs.shape[1] != self.n_streams:
-            raise ValueError(
-                f"chunk stream dim {xs.shape[1]} != n_streams="
-                f"{self.n_streams} (xs: {xs.shape})")
-        outs, self.state, self._carry = self._steps(self.state, self._carry,
-                                                    xs)
+                    f"chunk stream dim {xs.shape[1]} != n_streams="
+                    f"{self.n_streams} (xs: {xs.shape})")
+        with _span("engine.launch"):
+            outs, self.state, self._carry = self._steps(self.state,
+                                                        self._carry, xs)
         self._n_steps += xs.shape[0]
         return outs[:, 0] if (squeeze and self.n_streams == 1) else outs
 

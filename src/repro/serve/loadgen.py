@@ -16,6 +16,7 @@ Wall-clock only ever appears as a measurement (tick walls, throughput).
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -57,6 +58,7 @@ class LoadRunSummary:
     peak_active: int              # max slots simultaneously in service
     ticks: int
     scale_info: dict | None      # fleet.remove_shard info (if fired)
+    tick_wall_s: list             # wall seconds of each router.tick()
 
 
 def run_fabric_load(router, arrivals, *, scale_down_at: int | None = None,
@@ -73,6 +75,7 @@ def run_fabric_load(router, arrivals, *, scale_down_at: int | None = None,
     uid2arr: dict[int, int] = {}
     scale_info = None
     peak = peak_full = peak_active = 0
+    walls: list[float] = []
     i = 0
     while True:
         tick = router.tick_no
@@ -86,7 +89,10 @@ def run_fabric_load(router, arrivals, *, scale_down_at: int | None = None,
             if not admitted:
                 results[i] = router.results[-1]
             i += 1
-        for res in router.tick():
+        t0 = time.perf_counter()
+        done = router.tick()
+        walls.append(time.perf_counter() - t0)
+        for res in done:
             results[uid2arr[res.uid]] = res
         active = router.active_slots()
         concurrent = active + router.queue_depth()
@@ -108,7 +114,7 @@ def run_fabric_load(router, arrivals, *, scale_down_at: int | None = None,
     return LoadRunSummary(results=results, peak_concurrent=peak,
                           peak_concurrent_full=peak_full,
                           peak_active=peak_active, ticks=router.tick_no,
-                          scale_info=scale_info)
+                          scale_info=scale_info, tick_wall_s=walls)
 
 
 def fabric_parity(arrivals, results, fleet) -> int:

@@ -36,13 +36,13 @@ load-generator gates).
 The router is deliberately wall-clock-free in its decisions: placement,
 admission, shedding, and rebalance all count ticks, so a seeded load run
 reproduces its entire event history exactly on any machine. Wall time is
-only *measured* (per-tick, for the latency gates).
+only *measured*, by whoever drives the ticks
+(:func:`repro.serve.loadgen.run_fabric_load` times each one).
 """
 from __future__ import annotations
 
 import collections
 import itertools
-import time
 from dataclasses import dataclass, field
 
 import jax
@@ -77,8 +77,6 @@ class RouterResult:
     submit_tick: int = 0
     done_tick: int = 0
     replayed: bool = False              # finished after an elastic replay
-    submit_wall: float = 0.0
-    done_wall: float = 0.0
 
     @property
     def latency_ticks(self) -> int:
@@ -98,7 +96,6 @@ class _Routed:
     suspect: bool = False
     replayed: bool = False
     submit_tick: int = 0
-    submit_wall: float = 0.0
 
 
 def _book() -> dict:
@@ -219,7 +216,6 @@ class StreamRouter:
         self.totals = _book()
         self.totals["rebalanced"] = 0
         self.tick_no = 0
-        self.tick_wall_s: list[float] = []
         self._uid = itertools.count()
         self._input_size = (self.fleet.dims.input_size if self.fleet
                             else self.ports[0].worker.engine.dims.input_size)
@@ -284,7 +280,6 @@ class StreamRouter:
                 "on_nonfinite='quarantine'/'allow'")
         uid = next(self._uid)
         s = self._place()
-        now = time.perf_counter()
         self.totals["submitted"] += 1
         self.books[s]["submitted"] += 1
         if len(self.queues[s]) >= self.policy.max_queue:
@@ -296,8 +291,7 @@ class StreamRouter:
                 error={"reason": "queue_full", "shard": s,
                        "depth": len(self.queues[s]),
                        "max_queue": self.policy.max_queue},
-                submit_tick=self.tick_no, done_tick=self.tick_no,
-                submit_wall=now, done_wall=now)
+                submit_tick=self.tick_no, done_tick=self.tick_no)
             self.books[s]["rejected"] += 1
             self.totals["rejected"] += 1
             self.results.append(res)
@@ -305,7 +299,7 @@ class StreamRouter:
         self.queues[s].append(_Routed(
             uid, frames, s,
             suspect=suspect and on_nonfinite == "quarantine",
-            submit_tick=self.tick_no, submit_wall=now))
+            submit_tick=self.tick_no))
         return uid, True
 
     # -- the tick ---------------------------------------------------------
@@ -330,8 +324,7 @@ class StreamRouter:
         res = RouterResult(
             rec.uid, rec.shard, status, outputs=outputs, stats=stats,
             error=error, submit_tick=rec.submit_tick,
-            done_tick=self.tick_no, replayed=rec.replayed,
-            submit_wall=rec.submit_wall, done_wall=time.perf_counter())
+            done_tick=self.tick_no, replayed=rec.replayed)
         self._account(rec, status, stats=stats)
         self.results.append(res)
         return res
@@ -339,7 +332,6 @@ class StreamRouter:
     def tick(self) -> list[RouterResult]:
         """One fabric tick: shed → admit → step → harvest. Returns the
         streams that reached a terminal status this tick."""
-        t0 = time.perf_counter()
         out = []
         # 1. shed queued requests past their tick deadline. Replayed
         # streams are exempt: they already paid their queue wait once and
@@ -364,7 +356,6 @@ class StreamRouter:
         else:
             out += self._tick_pool()
         self.tick_no += 1
-        self.tick_wall_s.append(time.perf_counter() - t0)
         return out
 
     def _tick_fabric(self) -> list[RouterResult]:

@@ -31,6 +31,10 @@ import numpy as np
 
 from repro.serve.engine import DeltaStreamEngine, GruStreamEngine, LmEngine
 
+# Host spans of the batcher tick, written into the profiler's trace (see
+# the engine's); the engine step between them writes its own.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class Request:
@@ -270,40 +274,48 @@ class GruStreamBatcher:
         (harvest decisions are cursor-based, never value-based), so the
         engine's device-side hot loop is never forced to drain per tick.
         """
-        self._admit()
+        if self.queue:
+            with _span("batcher.admit"):
+                self._admit()
         self.counters["ticks"] += 1
         active = [(sid, req) for sid, req in enumerate(self.slots)
                   if req is not None]
         if not active:
             return []
-        x = self._idle_x
-        for sid, req in active:
-            x[sid] = req.frames[req.cursor]
-        # Hand the engine a SNAPSHOT (numpy copy, synchronous), never the
-        # persistent per-tick buffer: the engine's step is dispatched
-        # asynchronously and jax's host->device ingestion of a numpy
-        # buffer is itself deferred, so an aliased buffer mutated by the
-        # NEXT tick's frame writes nondeterministically bled future frames
-        # into the in-flight step under load (the batcher parity tests
-        # flaked with exactly that cross-tick corruption).
-        out = jnp.reshape(self.engine.step(x.copy()),
-                          (self.engine.n_streams, -1))
+        with _span("batcher.stage"):
+            x = self._idle_x
+            for sid, req in active:
+                x[sid] = req.frames[req.cursor]
+            # Hand the engine a SNAPSHOT (numpy copy, synchronous), never
+            # the persistent per-tick buffer: the engine's step is
+            # dispatched asynchronously and jax's host->device ingestion of
+            # a numpy buffer is itself deferred, so an aliased buffer
+            # mutated by the NEXT tick's frame writes nondeterministically
+            # bled future frames into the in-flight step under load (the
+            # batcher parity tests flaked with exactly that cross-tick
+            # corruption).
+            x = x.copy()
+        y = self.engine.step(x)
         finished = []
-        host_carry = None
-        for sid, req in active:
-            req.outputs.append(out[sid])         # device slice, no sync
-            req.cursor += 1
-            if req.cursor >= len(req.frames):
-                if host_carry is None:           # one sync per tick, shared
-                    host_carry = jax.device_get(self.engine._carry)
-                req.stats = self.engine.close_stream(sid,
-                                                     host_carry=host_carry)
-                req.outputs = list(np.asarray(jnp.stack(req.outputs)))
-                req.done = True
-                finished.append(req)
-                self.slots[sid] = None
+        with _span("batcher.slice"):
+            out = jnp.reshape(y, (self.engine.n_streams, -1))
+            for sid, req in active:
+                req.outputs.append(out[sid])     # device slice, no sync
+                req.cursor += 1
+                if req.cursor >= len(req.frames):
+                    finished.append((sid, req))
+        if finished:
+            with _span("batcher.harvest"):
+                # one sync per tick, shared by every stream that finished
+                host_carry = jax.device_get(self.engine._carry)
+                for sid, req in finished:
+                    req.stats = self.engine.close_stream(
+                        sid, host_carry=host_carry)
+                    req.outputs = list(np.asarray(jnp.stack(req.outputs)))
+                    req.done = True
+                    self.slots[sid] = None
         self.counters["harvested"] += len(finished)
-        return finished
+        return [req for _, req in finished]
 
     def run_until_drained(self, max_ticks: int = 100000,
                           strict: bool = True):
