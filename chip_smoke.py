@@ -122,8 +122,8 @@ def phase_stream(params, task, backend: str, frames,
     prog = compile_delta_program(params, backend=backend,
                                  interpret=interpret)
     got, compile_s, eng = _stream_once(prog, task, frames, thresholds)
-    kernel = _has_kernel(eng._step, eng.state, eng._carry,
-                         np.asarray(frames[:1]))
+    kernel = _has_kernel(eng._step1, eng.state, eng._carry,
+                         np.asarray(frames[0]))
     if backend == "fused":
         dense = compile_delta_program(params, backend="dense")
         with jax.default_matmul_precision("highest"):

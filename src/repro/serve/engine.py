@@ -468,8 +468,23 @@ class DeltaStreamEngine:
         self._reset_streams_fn = _reset_streams
         self._merge_rows_fn = _merge_rows
 
+        # Batch 1: the ``[I]`` -> ``[1, I]`` reshape and the ``[0]`` slice
+        # of the output run inside the compiled step, so a single-stream
+        # tick dispatches ONE call (an eager op on the host costs a whole
+        # dispatch). ``x`` may be ``[I]`` or ``[1, I]``; ``xs`` is
+        # ``[T, I]``.
+        def _step1(state, carry, x):
+            out, state, carry = _one_step(state, carry, x.reshape(1, -1))
+            return out[0], state, carry
+
+        def _steps1(state, carry, xs):
+            outs, state, carry = _steps(state, carry, xs[:, None, :])
+            return outs[:, 0], state, carry
+
         self._step = jax.jit(_step)
         self._steps = jax.jit(_steps)
+        self._step1 = jax.jit(_step1)
+        self._steps1 = jax.jit(_steps1)
         self._reset_streams = jax.jit(_reset_streams)
         self._merge_rows = jax.jit(_merge_rows)
         self.reset()
@@ -482,6 +497,8 @@ class DeltaStreamEngine:
         ``x: [I]`` (single stream) or ``[n_streams, I]``; returns ``[O]`` /
         ``[n_streams, O]``. The returned array is a device array — reading
         it (or :attr:`stats`) is what synchronizes, not the call itself.
+        A single-stream engine takes ``[I]`` or ``[1, I]`` and makes ONE
+        compiled call: the reshape and the ``[0]`` slice run inside it.
 
         The shape is validated like :meth:`step_many`'s: an earlier
         revision did ``x.reshape(self.n_streams, -1)``, which silently
@@ -504,25 +521,22 @@ class DeltaStreamEngine:
                 x = np.array(x, np.float32)
             x = jnp.asarray(x, jnp.float32)
             i_dim = self.dims.input_size
-            if x.ndim == 1 and self.n_streams == 1:
-                x = x[None]
-            if x.shape != (self.n_streams, i_dim):
-                want = (f"[{i_dim}]" if self.n_streams == 1
+            single = self.n_streams == 1
+            if x.shape != (self.n_streams, i_dim) and not (
+                    single and x.shape == (i_dim,)):
+                want = (f"[{i_dim}]" if single
                         else f"[{self.n_streams}, {i_dim}]")
                 raise ValueError(
                     f"engine has n_streams={self.n_streams}; step needs one "
                     f"frame per stream slot, shape {want}"
-                    f"{f' or [1, {i_dim}]' if self.n_streams == 1 else ''}, "
+                    f"{f' or [1, {i_dim}]' if single else ''}, "
                     f"got {tuple(x.shape)} — reshaping would silently "
                     "cross-contaminate stream slots")
         with _span("engine.launch"):
-            out, self.state, self._carry = self._step(self.state,
-                                                      self._carry, x)
+            step = self._step1 if single else self._step
+            out, self.state, self._carry = step(self.state, self._carry, x)
         self._n_steps += 1
-        if self.n_streams != 1:
-            return out
-        with _span("engine.unpack"):
-            return out[0]
+        return out
 
     def step_many(self, xs: np.ndarray | Array) -> Array:
         """Process a chunk of timesteps in ONE device call (``lax.scan``).
@@ -546,16 +560,16 @@ class DeltaStreamEngine:
                         f"needs [T, {self.n_streams}, I], got {xs.shape} "
                         "(a 2-D chunk would silently broadcast one "
                         "stream's input to all streams)")
-                xs = xs[:, None, :]
             elif xs.shape[1] != self.n_streams:
                 raise ValueError(
                     f"chunk stream dim {xs.shape[1]} != n_streams="
                     f"{self.n_streams} (xs: {xs.shape})")
         with _span("engine.launch"):
-            outs, self.state, self._carry = self._steps(self.state,
-                                                        self._carry, xs)
+            steps = self._steps1 if squeeze else self._steps
+            outs, self.state, self._carry = steps(self.state, self._carry,
+                                                  xs)
         self._n_steps += xs.shape[0]
-        return outs[:, 0] if (squeeze and self.n_streams == 1) else outs
+        return outs
 
     # -- per-stream sessions ----------------------------------------------
 
