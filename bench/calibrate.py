@@ -8,8 +8,9 @@ For each rate (default: the mix's own) and seed, one run of the cell as
 ``bench/run.py`` makes it, with tracing off, printed as one JSON line:
 the end-to-end metrics, the queue at the window's open and close, the
 numbers compared with their limits and, with ``--controls``, the same
-numbers for each named control (``harness.main.CONTROLS``: the reference
-at a lower weight width or matmul precision) put in the program's place.
+numbers for each named control of the configuration's model family (its
+``controls``: for ``gru`` the reference at a lower weight width or matmul
+precision) put in the program's place.
 The limits of ``bench/configs`` and the rates of ``bench/traffic``
 were set from these lines. Needs the cell's TPU chips, like ``run.py``.
 """
@@ -50,6 +51,13 @@ def run(argv) -> int:
     prec = cell.config.get("matmul_precision")
     if prec:
         jax.config.update("jax_default_matmul_precision", prec)
+    known = cell.family().controls
+    controls = [c for c in a.controls.split(",") if c]
+    if set(controls) - set(known):
+        print(f"calibrate: the family has no control "
+              f"{sorted(set(controls) - set(known))}; it has {sorted(known)}",
+              file=sys.stderr)
+        return 2
     rates = [None] if not a.rates else [float(r) for r in a.rates.split(",")]
     for rate in rates:
         c = copy.copy(cell)
@@ -59,9 +67,7 @@ def run(argv) -> int:
         for seed in (int(s) for s in a.seeds.split(",")):
             t0 = time.perf_counter()
             res = hm.run_cell(c, seed, a.seconds, False, t0,
-                              controls=[c for c in a.controls.split(",")
-                                        if c],
-                              log=lambda *x, **k: None)
+                              controls=controls, log=lambda *x, **k: None)
             line = {"workload": a.workload, "seed": seed, "rate": rate,
                     "metrics": {k: v["value"]
                                 for k, v in res["metrics"].items()},

@@ -21,7 +21,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from harness import model, serve, spec, traffic
+from harness import serve, spec, traffic
 from harness import trace as tr
 
 TRACE_SPAN_S = 3.0          # the trace covers the window's first seconds
@@ -75,7 +75,7 @@ def _front(cell: spec.Cell, prog, sched, spans: serve.Spans):
     """Build, warm and instrument the cell's entry point."""
     from repro.serve.engine import DeltaStreamEngine
     t = cell.traffic
-    task, theta = model.task(cell.config)
+    task, theta = cell.family().task(cell.config)
     f0 = sched.frames[0]
     if t["entry"] == "engine":
         eng = DeltaStreamEngine(prog, task, thresholds=theta)
@@ -140,7 +140,6 @@ def check(cell: spec.Cell, w: dict, sched, run: serve.Run, *,
     reference replayed over the same frames from the same start. Returns
     the compared numbers and, for a traced run, the reference's fired
     column counts of the traced ticks."""
-    cfg = cell.config
     ref = cell.reference()
     streams = run.streams_in_window()
     frames = [sched.frames[s][:run.served[s]] for s in streams]
@@ -152,8 +151,8 @@ def check(cell: spec.Cell, w: dict, sched, run: serve.Run, *,
             k = run.serve_ticks(s) - ka
             st.append(np.where((k >= 0) & (k < kb - ka), k, -1))
         kw = dict(serve_ticks=st, n_ticks=kb - ka)
-    out = ref.replay(w, frames, cfg["theta_x"], cfg["theta_h"],
-                     bits=cfg["weight_bits"], **kw)
+    out = ref.replay(w, frames, **cell.family().reference_kwargs(cell.config),
+                     **kw)
     want, work = (out[0], {"union": out[1], "counts": out[2]}) \
         if traced is not None else (out, None)
     missing, n, worst = 0, 0, 0.0
@@ -173,22 +172,16 @@ def check(cell: spec.Cell, w: dict, sched, run: serve.Run, *,
             "frames_checked": sum(len(f) for f in frames)}, work
 
 
-# The controls: the plain reference, put in the program's place, at the
-# weight width or the matmul precision below the configuration's.
-CONTROLS = {"int4": {"bits": 4}, "high": {"precision": "high"},
-            "default": {"precision": "default"}}
-
-
 def control_run(cell: spec.Cell, w: dict, sched, run: serve.Run,
                 control: str) -> serve.Run:
-    """The run with the reference at ``CONTROLS[control]`` put in the
-    program's place: its outputs for every frame the program served."""
-    cfg = cell.config
+    """The run with the reference, at the family's control ``control``,
+    put in the program's place: its outputs for every frame the program
+    served."""
+    fam = cell.family()
     streams = run.streams_in_window()
     frames = [sched.frames[s][:run.served[s]] for s in streams]
-    kw = {"bits": cfg["weight_bits"], **CONTROLS[control]}
-    outs = cell.reference().replay(w, frames, cfg["theta_x"], cfg["theta_h"],
-                                   **kw)
+    kw = {**fam.reference_kwargs(cell.config), **fam.controls[control]}
+    outs = cell.reference().replay(w, frames, **kw)
     new = list(run.outputs)
     for s, y in zip(streams, outs):
         new[s] = y
@@ -268,20 +261,21 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              peaks: dict | None = None, controls=(), log=print) -> dict:
     """Everything after the device check; returns the result line.
     ``peaks`` defaults to the table's row for the device's kind. For each
-    name of ``CONTROLS`` in ``controls`` the line also carries, under
-    ``control``, the numbers compared when that control stands in for the
-    program, and whether they pass (a control has to come out as not
-    correct)."""
+    of the family's controls named in ``controls`` the line also carries,
+    under ``control``, the numbers compared when that control stands in
+    for the program, and whether they pass (a control has to come out as
+    not correct)."""
     import jax
     cfg, t = cell.config, cell.traffic
     hop = t["hop_ms"] * 1e-3
     n_pre = int(round(t["pre_window_s"] / hop))
     phases = {"start": time.perf_counter() - t_process}
-    w = model.weights(cfg, seed)
+    fam = cell.family()
+    w = fam.weights(cfg, seed)
     phases["weights"] = time.perf_counter() - t_process
-    prog = model.program(cfg, w)
+    prog = fam.program(cfg, w)
     phases["program"] = time.perf_counter() - t_process
-    sched = traffic.build(t, cfg["input_size"], seed, seconds, hop)
+    sched = traffic.build(t, cfg, seed, seconds, hop)
     phases["traffic"] = time.perf_counter() - t_process
     spans = serve.Spans(trace)
     workdir = workdir or os.path.join(spec.ROOT, ".bench_work")
@@ -348,7 +342,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         red = reduce_trace(tr.extract(trace_dir), run, traced, len(devs))
         reading = Reading(cell=cell, run=run, spans=spans.arrays(),
                           peaks=peaks or spec.peaks(kind), chips=len(devs),
-                          dense_weights=model.dense_weights(cfg),
+                          dense_weights=fam.dense_weights(cfg),
                           trace=red, work={**work, "traced": traced})
         readers = cell.readers()
         for m in cell.per_layer:

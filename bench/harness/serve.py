@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 LEAD_S = 0.02            # from the end of set-up to tick 0
+BLOCK_BYTES = 1 << 22    # outputs are kept in blocks of about this size
 
 
 def pace(due: float) -> None:
@@ -112,6 +113,28 @@ class Tracer:
     def before(self, k: int, n_pre: int) -> None:
         if self.running and k == self.stop:
             self.off(n_pre, k)
+
+
+class Rows:
+    """Per-frame outputs stored as they are served, in blocks of about
+    ``BLOCK_BYTES`` allocated as they fill (the first output fixes the
+    shape and dtype), so a wide output costs memory only for the frames
+    served."""
+
+    def __init__(self):
+        self.blocks, self.base, self.end = [], 0, 0
+
+    def put(self, k: int, y) -> None:
+        """Store the output of frame ``k``; frames come in order."""
+        if k >= self.end:
+            rows = max(1, BLOCK_BYTES // max(y.nbytes, 1))
+            self.blocks.append(np.empty((rows, *y.shape), y.dtype))
+            self.base, self.end = k, k + rows
+        self.blocks[-1][k - self.base] = y
+
+    def take(self, n: int) -> np.ndarray:
+        """The first ``n`` outputs as one array."""
+        return np.concatenate(self.blocks)[:n]
 
 
 @dataclass
@@ -218,29 +241,30 @@ def engine_loop(eng, frames: np.ndarray, *, hop: float, n_pre: int,
     fetch = spans.timed("bench.output", np.asarray)
     if closed:
         return _closed_loop(step, fetch, frames, hop=hop, n_pre=n_pre,
-                            seconds=seconds, spans=spans, tracer=tracer,
-                            o_dim=eng.task.output_size)
+                            seconds=seconds, spans=spans, tracer=tracer)
     n = n_pre + int(round(seconds / hop))
-    outs = np.empty((n, eng.task.output_size), np.float32)
+    outs = Rows()
+    put = outs.put
 
     def tick(k):
-        outs[k] = fetch(step(frames[k]))
+        put(k, fetch(step(frames[k])))
 
     t0, due, start, done, cpu = _paced(n, n_pre, hop, spans, tracer, tick)
     return Run(hop=hop, t0=t0, n_pre=n_pre, tick_due=due, tick_start=start,
                tick_done=done, tick_cpu=cpu, window=(t0 + n_pre * hop, t0 + n * hop),
                arrival_tick=np.zeros(1, np.int64),
                adm_tick=np.zeros(1, np.int64), served=np.array([n]),
-               outputs=[outs])
+               outputs=[outs.take(n)])
 
 
-def _closed_loop(step, fetch, frames, *, hop, n_pre, seconds, spans, tracer,
-                 o_dim) -> Run:
+def _closed_loop(step, fetch, frames, *, hop, n_pre, seconds, spans,
+                 tracer) -> Run:
     """Send each frame when the previous output is back, for ``n_pre *
     hop`` seconds before the window and ``seconds`` in it."""
     cap = len(frames)
     due, done, cpu = np.empty(cap), np.empty(cap), np.empty(cap)
-    outs = np.empty((cap, o_dim), np.float32)
+    outs = Rows()
+    put = outs.put
     ann = jax.profiler.TraceAnnotation if spans.on else None
     t0 = time.perf_counter() + LEAD_S
     k0 = w0 = w1 = None
@@ -257,10 +281,10 @@ def _closed_loop(step, fetch, frames, *, hop, n_pre, seconds, spans, tracer,
             break
         c0 = time.process_time()
         if ann is None:
-            outs[k] = fetch(step(frames[k]))
+            put(k, fetch(step(frames[k])))
         else:
             with ann("bench.tick"):
-                outs[k] = fetch(step(frames[k]))
+                put(k, fetch(step(frames[k])))
         due[k], done[k] = ts, time.perf_counter()
         cpu[k] = time.process_time() - c0
         k += 1
@@ -274,7 +298,7 @@ def _closed_loop(step, fetch, frames, *, hop, n_pre, seconds, spans, tracer,
                window=(w0, w1),
                arrival_tick=np.zeros(1, np.int64),
                adm_tick=np.zeros(1, np.int64), served=np.array([k]),
-               outputs=[outs[:k]], closed=True)
+               outputs=[outs.take(k)], closed=True)
 
 
 # -- the batcher: many streams on one engine ----------------------------------
@@ -296,7 +320,7 @@ def warm_batcher(batcher, frames: np.ndarray, lengths) -> None:
         batcher.submit(frames[:2])
     batcher.run_until_drained()
     probe = jnp.reshape(eng.step(np.zeros(
-        (eng.n_streams, eng.dims.input_size), np.float32)),
+        (eng.n_streams, *frames.shape[1:]), frames.dtype)),
         (eng.n_streams, -1))
     _warm_harvest(probe[0], lengths)
 
@@ -352,8 +376,8 @@ def warm_router(router, frames: np.ndarray, lengths) -> None:
     for _ in range(fleet.n_streams + 1):
         router.submit(frames[:2])
     router.run_until_drained()
-    y = fleet.step(np.zeros((fleet.n_streams, fleet.dims.input_size),
-                            np.float32))
+    y = fleet.step(np.zeros((fleet.n_streams, *frames.shape[1:]),
+                            frames.dtype))
     for sh in range(fleet.n_shards):
         _warm_harvest(y[fleet.shard_slots(sh)[0]], lengths)
 

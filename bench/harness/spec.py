@@ -3,11 +3,28 @@
 A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``); a per-layer metric is the
 reader ``bench/metrics/<name>.py``; a configuration names its plain
-reference, ``bench/reference/<reference>.py``. Adding any of them is adding
-a file: nothing here changes.
+reference, ``bench/reference/<reference>.py``, and its model family,
+``bench/models/<model>.py`` (``gru`` where it names none); a mix's frames
+of a kind the generator does not build in come from
+``bench/traffic/kinds/<kind>.py``. Adding any of them is adding a file:
+nothing here changes.
+
+A family module provides ``weights(config, seed)``, ``program(config,
+w)``, ``task(config) -> (task, ThresholdPolicy)``,
+``dense_weights(config)``, ``frame_spec(config) -> (shape, dtype)`` of one
+stream-frame, ``reference_kwargs(config)`` (what the reference's
+``replay`` takes from the configuration besides the weights and frames),
+``controls`` (name -> the reference's keyword arguments of each control)
+and ``tiny(config, **kw)``, the configuration at the CPU rehearsal's
+size. A reference provides ``replay(w, streams, **kwargs)``: the outputs
+of every stream of frames, and with ``serve_ticks`` and ``n_ticks``
+``(outputs, union, counts)``, the fired columns of each served tick (see
+``bench/reference/deltagru_q8.py``). A frame kind provides ``frames(rng,
+n, config, params) -> [n, *shape]`` of the family's ``frame_spec``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -44,6 +61,9 @@ class Cell:
             BENCH, "reference", self.config["reference"] + ".py"),
             "bench_reference_" + self.config["reference"])
 
+    def family(self):
+        return load_family(self.config)
+
     def readers(self) -> dict:
         """``name -> module`` of this cell's per-layer readers."""
         return {m["name"]: load_reader(m["name"]) for m in self.per_layer}
@@ -53,6 +73,30 @@ def load_reader(name: str):
     """The reader of the per-layer metric ``name``."""
     return load_module(os.path.join(BENCH, "metrics", name + ".py"),
                        "bench_metric_" + name.replace(".", "_"))
+
+
+@functools.cache
+def _family(name: str):
+    return load_module(os.path.join(BENCH, "models", name + ".py"),
+                       "bench_model_" + name)
+
+
+def load_family(config: dict):
+    """The model family module of a configuration."""
+    return _family(config.get("model", "gru"))
+
+
+@functools.cache
+def load_kind(name: str):
+    """The frame kind module ``name``."""
+    return load_module(os.path.join(BENCH, "traffic", "kinds", name + ".py"),
+                       "bench_kind_" + name.replace("-", "_"))
+
+
+def workloads() -> list:
+    """The names of the cells of ``BENCHMARK.json``, in its order."""
+    return [w["name"] for w in
+            _json(os.path.join(ROOT, "BENCHMARK.json"))["workloads"]]
 
 
 def _reports(metric: dict, cell: str) -> bool:

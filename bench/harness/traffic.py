@@ -22,6 +22,13 @@ arrivals and the frames. Everything here is drawn from ``--seed``:
 * ``frames.kind = "sensor"``: gas-sensor-like frames after ``gas_batch``: a
   slow Ornstein-Uhlenbeck concentration driving 14 power-law sensors with
   a random-walk baseline drift and noise.
+* any other ``frames.kind``: the frame kind ``bench/traffic/kinds/<kind>.py``
+  (``frames(rng, n, config, params)``), in the shape of the
+  configuration's family's ``frame_spec`` and in the kind's own dtype.
+
+The two built-in kinds are float32 ``[n, width]`` at the width of the
+family's ``frame_spec``; a ``room_tone`` in their parameters adds a
+per-stream constant to each stream's frames.
 """
 from __future__ import annotations
 
@@ -30,6 +37,8 @@ from statistics import NormalDist
 
 import numpy as np
 from scipy.signal import lfilter
+
+from harness import spec
 
 
 @dataclass
@@ -103,9 +112,15 @@ def sensor_frames(rng: np.random.Generator, n: int, i_dim: int,
 FRAMES = {"speech": speech_frames, "sensor": sensor_frames}
 
 
-def _frames(rng, lengths, i_dim, fp) -> list:
-    """Cut one long signal into streams; each stream has its own tone."""
+def _frames(rng, lengths, config, fp) -> list:
+    """Cut one long signal into streams; with a built-in kind each stream
+    has its own tone."""
     total = int(np.sum(lengths))
+    if fp["kind"] not in FRAMES:
+        x = spec.load_kind(fp["kind"]).frames(rng, total, config, fp)
+        return [np.ascontiguousarray(f)
+                for f in np.split(x, np.cumsum(lengths)[:-1])]
+    (i_dim,), _ = spec.load_family(config).frame_spec(config)
     x = FRAMES[fp["kind"]](rng, total, i_dim, fp)
     out, at = [], 0
     for n in lengths:
@@ -141,9 +156,10 @@ def residual_set(lengths: np.ndarray, n: int) -> np.ndarray:
     return (np.searchsorted(cdf, u) + 1).astype(np.int64)
 
 
-def build(traffic: dict, i_dim: int, seed: int, seconds: float,
+def build(traffic: dict, config: dict, seed: int, seconds: float,
           hop_s: float) -> Schedule:
-    """The schedule of a run of ``seconds`` after ``pre_window_s``."""
+    """The schedule of a run of ``seconds`` after ``pre_window_s``, with
+    frames for the configuration ``config``."""
     rng = np.random.default_rng(seed)
     arr, fp = traffic["arrivals"], traffic["frames"]
     pre = traffic["pre_window_s"]
@@ -153,7 +169,7 @@ def build(traffic: dict, i_dim: int, seed: int, seconds: float,
         else:
             n = int(round((pre + seconds) / hop_s))
         return Schedule(np.zeros(1, np.int64),
-                        _frames(rng, np.array([n]), i_dim, fp))
+                        _frames(rng, np.array([n]), config, fp))
     if arr["kind"] != "poisson":
         raise ValueError(f"unknown arrivals kind {arr['kind']!r}")
     rate, horizon = arr["rate_per_s"], pre + seconds
@@ -172,4 +188,4 @@ def build(traffic: dict, i_dim: int, seed: int, seconds: float,
             length_set(arr["length"], 4096), n0))
         ticks = np.concatenate([np.zeros(n0, np.int64), ticks])
         lengths = np.concatenate([res, lengths])
-    return Schedule(ticks, _frames(rng, lengths, i_dim, fp))
+    return Schedule(ticks, _frames(rng, lengths, config, fp))

@@ -4,4 +4,5 @@ from harness.spec import load_reader
 
 _base = load_reader("delta_q8_roofline")
 LAYER, UNIT, read = _base.LAYER, _base.UNIT, _base.read
+DEVICE_ONLY = _base.DEVICE_ONLY
 MOVES = "frame_latency_p50_ms.batched"

@@ -8,7 +8,8 @@ The kernel is the ``pallas_call`` of ``kernels/delta_q8.py``. On a TPU
 the trace names its events by the HLO of the custom call, after the
 jitted function that wraps the kernel (``_fused_q8_step``, and
 ``_fused_q8_step_dbuf`` for the double-buffered variant), with the target
-``tpu_custom_call``: ``KERNELS`` matches that text.
+``tpu_custom_call``: ``KERNELS`` matches that text. Off a TPU the kernel
+is not run, and the reader stays silent (``DEVICE_ONLY``).
 """
 import os
 import re
@@ -16,11 +17,12 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _work import least_seconds  # noqa: E402
-from harness.model import GATES  # noqa: E402
 
 LAYER = "kernels (kernels/delta_q8.py)"
 UNIT = "%"
 MOVES = "frame_latency_p50_ms"
+DEVICE_ONLY = True
+GATES = {"gru": 3, "lstm": 4}       # the kernel's weight rows per hidden unit
 KERNELS = r"^%_fused_q8_step(_dbuf)?(\.\d+)? = .*tpu_custom_call"
 
 

@@ -1,7 +1,7 @@
 """Host time of the engine's input hand-off per tick: the span
 ``engine.h2d`` in ``DeltaStreamEngine.step`` (the numpy snapshot, the
-copy to the device, the ``[I]`` -> ``[1, I]`` reshape and the shape
-check)."""
+copy to the device and the shape check; a single-stream engine reshapes
+``[I]`` -> ``[1, I]`` inside its jitted step, under ``engine.launch``)."""
 import os
 import sys
 

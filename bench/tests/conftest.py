@@ -19,20 +19,13 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 import pytest  # noqa: E402
 
 
-# The committed limits hold the program to the reference as the chip runs
-# both: bit for bit there (0.0 on every run). On the CPU, XLA sums the
-# float32 head of a batch-1 step in another order than the reference's
-# scan, a few units in the last place (up to 5.5e-7 of the outputs' RMS
-# at width 16). The rehearsal holds the program to this instead; every
-# planted fault reads orders of magnitude above it.
-CPU_OUT_GAP = 1e-5
-
-
-def tiny(cell, hidden=16, slots=8, rate=20.0, max_len=300):
-    """A cell's files at a tiny width and load, for the CPU."""
+def tiny(cell, slots=8, rate=20.0, max_len=300, **kw):
+    """A cell's files at a tiny size and load, for the CPU: the
+    configuration as its model family's ``tiny`` gives it (``kw`` goes
+    there), the traffic at a few slots and short streams."""
+    from harness import spec
     cell = copy.copy(cell)
-    cell.config = {**cell.config, "hidden_size": hidden,
-                   "limits": {"out_gap": CPU_OUT_GAP}}
+    cell.config = spec.load_family(cell.config).tiny(cell.config, **kw)
     t = copy.deepcopy(cell.traffic)
     if t["entry"] != "engine":
         t["slots"] = slots

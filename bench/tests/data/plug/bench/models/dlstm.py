@@ -1,9 +1,9 @@
-"""Weights from the seed, and the system under test built from them.
+"""The delta-LSTM family (``core/deltalstm.py``, four gates i/f/g/o and a
+cell state) served in float32 on the ``dense`` backend: weights from the
+seed, and the system under test built from them.
 
-The weights are made on the device in one jitted call, in float32 (the
-type the program quantizes from), and handed both to the program and, as
-they are, to the plain reference: nothing the program derives from them
-reaches the reference.
+The weights are made on the device in one jitted call, in float32, and
+handed both to the program and, as they are, to the plain reference.
 """
 from __future__ import annotations
 
@@ -12,23 +12,28 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-GATES = {"gru": 3, "lstm": 4}       # weight rows per hidden unit of a cell
+# The control: the plain reference, put in the program's place, with its
+# products on operands rounded to bfloat16, the precision below float32.
+controls = {"default": {"precision": "default"}}
 
 
 @functools.partial(jax.jit, static_argnames=("i", "h", "layers", "o"))
 def _weights(key, *, i: int, h: int, layers: int, o: int) -> dict:
-    """Glorot-uniform gate weights and biases, a normal head."""
+    """Glorot-uniform gate weights, a forget-gate bias of 1, a normal
+    head."""
     keys = jax.random.split(key, layers + 1)
     out = []
     for l in range(layers):
         il = i if l == 0 else h
-        kx, kh, kb = jax.random.split(keys[l], 3)
-        sx = math.sqrt(6.0 / (il + 3 * h))
-        sh = math.sqrt(6.0 / (4 * h))
-        out.append((jax.random.uniform(kx, (3 * h, il), jnp.float32, -sx, sx),
-                    jax.random.uniform(kh, (3 * h, h), jnp.float32, -sh, sh),
-                    jax.random.uniform(kb, (3 * h,), jnp.float32, -sh, sh)))
+        kx, kh = jax.random.split(keys[l])
+        sx = math.sqrt(6.0 / (il + 4 * h))
+        sh = math.sqrt(6.0 / (5 * h))
+        b = jnp.zeros((4 * h,), jnp.float32).at[h:2 * h].set(1.0)
+        out.append((jax.random.uniform(kx, (4 * h, il), jnp.float32, -sx, sx),
+                    jax.random.uniform(kh, (4 * h, h), jnp.float32, -sh, sh),
+                    b))
     kw, kb = jax.random.split(keys[-1])
     return {"layers": out,
             "head": jax.random.normal(kw, (h, o), jnp.float32) / math.sqrt(h),
@@ -43,24 +48,20 @@ def weights(config: dict, seed: int) -> dict:
 
 
 def dense_weights(config: dict) -> int:
-    """Weights a dense step of the stack and head multiplies, unpadded:
-    ``3H (I_l + H)`` per layer plus ``H O``."""
+    """``4H (I_l + H)`` per layer plus ``H O``."""
     i, h = config["input_size"], config["hidden_size"]
-    gates = GATES[config["cell"]]
-    stack = sum(gates * h * ((i if l == 0 else h) + h)
+    stack = sum(4 * h * ((i if l == 0 else h) + h)
                 for l in range(config["num_layers"]))
     return stack + h * config["output_size"]
 
 
 def program(config: dict, w: dict):
-    """The compiled program of the system under test."""
-    from repro.core.deltagru import GruLayerParams
+    from repro.core.deltalstm import LstmLayerParams
     from repro.core.program import compile_delta_program
-    params = {"gru": [GruLayerParams(*t) for t in w["layers"]],
+    params = {"lstm": [LstmLayerParams(*t) for t in w["layers"]],
               "head": w["head"], "head_b": w["head_b"]}
     return compile_delta_program(params, backend=config["backend"],
-                                 cell=config["cell"],
-                                 block=config["block"])
+                                 cell="lstm")
 
 
 def task(config: dict):
@@ -71,3 +72,15 @@ def task(config: dict):
                       task=config["task"], theta_x=config["theta_x"],
                       theta_h=config["theta_h"])
     return t, ThresholdPolicy(config["theta_x"], config["theta_h"])
+
+
+def frame_spec(config: dict):
+    return (config["input_size"],), np.float32
+
+
+def reference_kwargs(config: dict) -> dict:
+    return {"theta_x": config["theta_x"], "theta_h": config["theta_h"]}
+
+
+def tiny(config: dict, hidden: int = 16) -> dict:
+    return {**config, "hidden_size": hidden}

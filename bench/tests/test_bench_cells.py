@@ -8,8 +8,7 @@ import pytest
 
 from harness import main, spec
 
-CELLS = ["gru768-q8-b1-speech", "gru768-q8-s128-speech",
-         "gas256-q8-b1-closed"]
+CELLS = spec.workloads()
 PEAKS = spec.peaks("TPU v5 lite")
 
 

@@ -42,6 +42,7 @@ def test_benchmark_json_names_files_that_exist():
         cell = spec.load_cell(w["name"])
         assert cell.config["name"] == w["config"]
         cell.reference()
+        cell.family()
         for name, mod in cell.readers().items():
             entry = next(m for m in b["per_layer"] if m["name"] == name)
             assert (mod.LAYER, mod.UNIT, mod.MOVES) == (

@@ -5,8 +5,9 @@ import time
 from types import SimpleNamespace
 
 import numpy as np
+import pytest
 
-from harness import model, serve, traffic
+from harness import serve, traffic
 
 HOP = 0.05          # long enough that a loaded test machine keeps time
 
@@ -72,9 +73,10 @@ def test_queued_stream_carries_its_wait(tiny_cell):
     from repro.serve.engine import DeltaStreamEngine
     from repro.serve.scheduler import DeltaStreamBatcher
     cell = tiny_cell("gru768-q8-s128-speech", slots=1)
-    w = model.weights(cell.config, 0)
-    task, theta = model.task(cell.config)
-    eng = DeltaStreamEngine(model.program(cell.config, w), task,
+    fam = cell.family()
+    w = fam.weights(cell.config, 0)
+    task, theta = fam.task(cell.config)
+    eng = DeltaStreamEngine(fam.program(cell.config, w), task,
                             thresholds=theta, n_streams=2)
     b = DeltaStreamBatcher(eng)
     rng = np.random.default_rng(0)
@@ -94,3 +96,38 @@ def test_queued_stream_carries_its_wait(tiny_cell):
     assert np.all(third >= 10 * HOP * 1e3 - 1)
     assert first.max() < 6 * HOP * 1e3
     assert [len(o) for o in run.outputs] == [6, 4, 3]
+
+
+class _TokenEngine:
+    """``step`` records each frame's type and shape and, after a
+    millisecond, answers with a row of ``width`` logits that carries the
+    frame."""
+
+    def __init__(self, width):
+        self.width, self.seen = width, []
+
+    def step(self, x):
+        self.seen.append((x.dtype, x.shape))
+        time.sleep(1e-3)
+        y = np.zeros(self.width, np.float32)
+        y[0] = x.sum()
+        return y
+
+
+@pytest.mark.parametrize("closed", [False, True])
+def test_token_frames_reach_step_unchanged_and_wide_outputs_grow(closed):
+    """An ``int32`` schedule of token ids reaches ``step`` as it is, and
+    a 100,352-wide output is kept in blocks grown as frames are served
+    (ten rows to a block here), not preallocated for the whole cap."""
+    eng = _TokenEngine(100_352)
+    frames = np.arange(5000, dtype=np.int32)[:, None]
+    run = serve.engine_loop(eng, frames, hop=0.002, n_pre=2, seconds=0.1,
+                            closed=closed, spans=serve.Spans(False),
+                            tracer=serve.Tracer(None))
+    n = int(run.served[0])
+    assert n > 2 * serve.BLOCK_BYTES // (4 * 100_352)
+    assert set(eng.seen) == {(np.dtype(np.int32), (1,))}
+    assert len(eng.seen) == n
+    out = run.outputs[0]
+    assert out.shape == (n, 100_352) and out.dtype == np.float32
+    assert np.array_equal(out[:, 0], np.arange(n))

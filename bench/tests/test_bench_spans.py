@@ -68,7 +68,6 @@ def test_a_window_with_no_harvest_reads_zero_beside_the_batchers_spans():
 @pytest.mark.parametrize("metric, span", [
     ("engine.h2d_ms_per_tick", "engine.h2d"),
     ("engine.launch_ms_per_tick", "engine.launch"),
-    ("engine.unpack_ms_per_tick", "engine.unpack"),
     ("engine.h2d_ms_per_tick.batched", "engine.h2d"),
     ("engine.launch_ms_per_tick.batched", "engine.launch"),
     ("batcher.slice_ms_per_tick", "batcher.slice"),

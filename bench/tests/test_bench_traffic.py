@@ -6,6 +6,8 @@ import pytest
 from harness import spec, traffic
 
 HOP = 0.01
+GRU768 = spec.load_cell("gru768-q8-b1-speech").config
+GAS256 = spec.load_cell("gas256-q8-b1-closed").config
 
 
 def _mix(name="s128-speech"):
@@ -14,7 +16,7 @@ def _mix(name="s128-speech"):
 
 def test_poisson_arrivals_sit_on_the_grid_at_the_rate():
     t = _mix()
-    s = traffic.build(t, 40, seed=3, seconds=30.0, hop_s=HOP)
+    s = traffic.build(t, GRU768, seed=3, seconds=30.0, hop_s=HOP)
     rate = t["arrivals"]["rate_per_s"]
     lengths = traffic.length_set(t["arrivals"]["length"], 4096)
     n0 = min(t["slots"], round(rate * lengths.mean() * HOP))
@@ -29,13 +31,13 @@ def test_poisson_arrivals_sit_on_the_grid_at_the_rate():
 
 def test_seeds_reorder_the_same_work():
     t = _mix()
-    a = traffic.build(t, 40, seed=1, seconds=10.0, hop_s=HOP)
-    b = traffic.build(t, 40, seed=2**31 + 99, seconds=10.0, hop_s=HOP)
+    a = traffic.build(t, GRU768, seed=1, seconds=10.0, hop_s=HOP)
+    b = traffic.build(t, GRU768, seed=2**31 + 99, seconds=10.0, hop_s=HOP)
     assert sorted(a.lengths) == sorted(b.lengths)
     assert not np.array_equal(a.lengths, b.lengths)
     assert not np.array_equal(a.arrival_tick, b.arrival_tick)
     assert not np.array_equal(a.frames[0][:1], b.frames[0][:1])
-    c = traffic.build(t, 40, seed=1, seconds=10.0, hop_s=HOP)
+    c = traffic.build(t, GRU768, seed=1, seconds=10.0, hop_s=HOP)
     assert all(np.array_equal(x, y) for x, y in zip(a.frames, c.frames))
 
 
@@ -53,7 +55,7 @@ def test_lognormal_lengths():
 
 def test_stationary_start_holds_rate_times_mean_length():
     t = _mix()
-    s = traffic.build(t, 40, seed=5, seconds=10.0, hop_s=HOP)
+    s = traffic.build(t, GRU768, seed=5, seconds=10.0, hop_s=HOP)
     lengths = traffic.length_set(t["arrivals"]["length"], 4096)
     want = min(t["slots"], round(t["arrivals"]["rate_per_s"]
                                  * lengths.mean() * HOP))
@@ -84,8 +86,8 @@ def test_frames_are_seeded_and_smooth(kind, i_dim):
 
 def test_one_stream_lasts_the_run():
     t = spec.load_cell("gru768-q8-b1-speech").traffic
-    s = traffic.build(t, 40, seed=0, seconds=20.0, hop_s=HOP)
+    s = traffic.build(t, GRU768, seed=0, seconds=20.0, hop_s=HOP)
     assert len(s.frames) == 1 and len(s.frames[0]) == 2100
     c = spec.load_cell("gas256-q8-b1-closed").traffic
-    s = traffic.build(c, 14, seed=0, seconds=2.0, hop_s=HOP)
+    s = traffic.build(c, GAS256, seed=0, seconds=2.0, hop_s=HOP)
     assert len(s.frames[0]) == c["max_frames_per_s"] * 3

@@ -6,7 +6,7 @@ import os
 
 import numpy as np
 
-from harness import model, spec
+from harness import spec
 
 BENCH = spec.BENCH
 
@@ -23,7 +23,7 @@ def test_theta_zero_fires_the_whole_unpadded_stack():
     cell = spec.load_cell("gru768-q8-b1-speech")
     cfg = cell.config
     ref = cell.reference()
-    w = model.weights(cfg, 0)
+    w = cell.family().weights(cfg, 0)
     rng = np.random.default_rng(0)
     streams = [rng.standard_normal((3, 40)).astype(np.float32)
                for _ in range(2)]
@@ -41,7 +41,7 @@ def test_theta_zero_fires_the_whole_unpadded_stack():
     assert np.array_equal(ops.sum(axis=1), 2 * 5_400_576 * np.array([2, 2, 1]))
     assert int(_work().needed(union, counts, 3, 768, 4)[1][0].sum()) \
         == 5_400_576 // 2
-    assert model.dense_weights(cfg) == 5_400_576 + 768 * 12
+    assert cell.family().dense_weights(cfg) == 5_400_576 + 768 * 12
 
 
 def test_least_seconds_takes_the_larger_bound():
