@@ -114,6 +114,7 @@ def _ensure_builtins() -> None:
     import repro.core.deltalstm   # noqa: F401  (registers lstm backends)
     import repro.core.deltarwkv   # noqa: F401  (registers rwkv6 backends)
     import repro.core.deltarglru  # noqa: F401  (registers rglru backends)
+    import repro.core.deltamamba2h  # noqa: F401  (registers mamba2h backends)
 
 
 def require_stream_tile(x, name: str) -> None:

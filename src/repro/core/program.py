@@ -5,9 +5,10 @@ packed into the DRAM layout once, and the streaming side only ever issues
 steps against that fixed program. :func:`compile_delta_program` is the
 software analogue — it resolves a :class:`~repro.core.backends.BackendSpec`
 from the registry for any registered **cell family** (``"gru"``,
-``"lstm"``, ``"rwkv6"`` and ``"rglru"`` builtin — the LM cells
-delta-threshold their projection banks, see :mod:`repro.core.deltarwkv` /
-:mod:`repro.core.deltarglru`), packs every layer's weights once
+``"lstm"``, ``"rwkv6"``, ``"rglru"`` and ``"mamba2h"`` builtin — the LM
+cells delta-threshold their projection banks, see
+:mod:`repro.core.deltarwkv` / :mod:`repro.core.deltarglru` /
+:mod:`repro.core.deltamamba2h`), packs every layer's weights once
 (quantizing them for
 ``fused_q8`` — for either cell family, ``compile`` of a trained fp32/QAT
 stack IS the int8 export), and returns an immutable :class:`DeltaProgram`:
@@ -47,6 +48,8 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.core.backends import BackendSpec, get_backend
 
@@ -80,8 +83,22 @@ def _cell_ops(cell: str) -> dict:
                 "step": m.deltarglru_stack_step,
                 "sequence": m.deltarglru_sequence,
                 "params_key": "rglru"}
+    if cell == "mamba2h":
+        from repro.core import deltamamba2h as m
+        return {"init": m.init_mamba2h_stack_state,
+                "step": m.deltamamba2h_stack_step,
+                "sequence": m.deltamamba2h_sequence,
+                "params_key": "mamba2h",
+                # token frames in, logits out through the tied embedding;
+                # the state (KV cache and SSM state) is updated in place
+                "head": m.mamba2h_head,
+                "frame_dtype": np.int32,
+                "frame_ok": m.frame_ok,
+                "watched": m.watched,
+                "max_context": m.max_context,
+                "in_place": True}
     raise ValueError(f"unknown cell family {cell!r}; known: "
-                     f"('gru', 'lstm', 'rwkv6', 'rglru')")
+                     f"('gru', 'lstm', 'rwkv6', 'rglru', 'mamba2h')")
 
 
 @dataclass(frozen=True)
@@ -156,6 +173,43 @@ class DeltaProgram:
     @property
     def hidden_size(self) -> int:
         return self.layers[-1].hidden_size
+
+    @property
+    def has_head(self) -> bool:
+        """Whether :meth:`apply_head` can make outputs: a compiled head,
+        or a family that brings its own (a tied embedding)."""
+        return self.head is not None or "head" in _cell_ops(self.cell)
+
+    @property
+    def frame_dtype(self):
+        """One stream-frame's dtype: float32 features, or int32 token ids
+        for a family with a token input stage."""
+        return _cell_ops(self.cell).get("frame_dtype", np.float32)
+
+    @property
+    def in_place(self) -> bool:
+        """Whether a server should donate the state to each step, so it is
+        updated in place (a family whose state holds a KV cache)."""
+        return _cell_ops(self.cell).get("in_place", False)
+
+    @property
+    def max_context(self) -> int | None:
+        """Tokens a stream's cache holds, for a family that keeps one."""
+        f = _cell_ops(self.cell).get("max_context")
+        return None if f is None else f(self.layers)
+
+    def frame_ok(self, x: Array) -> Array:
+        """Per component of a frame, whether it may enter the stack:
+        finite features, or token ids inside the vocabulary."""
+        f = _cell_ops(self.cell).get("frame_ok")
+        return jnp.isfinite(x) if f is None else f(self.layers, x)
+
+    def watched(self, state: DeltaProgramState):
+        """The part of a state a server scans for non-finite values after
+        each step (all of it, unless the family names a smaller part that
+        any corruption reaches in the same step)."""
+        f = _cell_ops(self.cell).get("watched")
+        return state.stack if f is None else f(state.stack)
 
     # -- states -----------------------------------------------------------
 
@@ -235,6 +289,9 @@ class DeltaProgram:
 
     def apply_head(self, ys: Array) -> Array:
         """Apply the compiled classifier/regression head (if any)."""
+        head = _cell_ops(self.cell).get("head")
+        if head is not None:
+            return head(self.layers, ys)
         if self.head is None:
             raise ValueError("program was compiled from a bare layer stack; "
                              "compile from a model params dict to carry "
@@ -308,8 +365,9 @@ def compile_delta_program(params, backend: str = "fused", *,
         into the program for serving).
       backend: any backend name registered for ``cell``; resolved once,
         here.
-      cell: the cell family (``"gru"``, ``"lstm"``, ``"rwkv6"`` or
-        ``"rglru"`` builtin).
+      cell: the cell family (``"gru"``, ``"lstm"``, ``"rwkv6"``,
+        ``"rglru"`` or ``"mamba2h"`` builtin; ``"mamba2h"`` compiles from
+        ``{"mamba2h": [Mamba2hParams]}``, the whole stack as one entry).
       layouts / packs: optional pre-packed per-layer kernel operands
         (e.g. the exact :func:`repro.quant.export.quantize_stack` layouts);
         packed from ``params`` otherwise. For ``backend="fused_q8"`` with
@@ -335,7 +393,8 @@ def compile_delta_program(params, backend: str = "fused", *,
         stack = list(params[key])
     else:
         stack = list(params)
-    if not stack or not isinstance(stack[0], tuple):
+    if not stack or not (isinstance(stack[0], tuple)
+                         or hasattr(stack[0], "cfg")):
         raise TypeError(f"compile_delta_program needs a non-empty {cell} "
                         f"layer-params stack; got {type(params).__name__}")
     if layouts is None and packs is None:

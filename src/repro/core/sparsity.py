@@ -106,7 +106,7 @@ class GruDims:
 CELL_GATES = {"gru": 3, "lstm": 4}
 
 
-def _rwkv6_volumes(i: int, h: int, l: int) -> tuple[int, int]:
+def _rwkv6_volumes(i: int, h: int, l: int, layers=None) -> tuple[int, int]:
     """RWKV6 time-mix delta-gated projection volumes per stack.
 
     Δx-group: the mixed r/k/v streams each gate a [D, D] projection
@@ -118,7 +118,7 @@ def _rwkv6_volumes(i: int, h: int, l: int) -> tuple[int, int]:
     return 3 * h * h * l, h * DECAY_LORA * l
 
 
-def _rglru_volumes(i: int, h: int, l: int) -> tuple[int, int]:
+def _rglru_volumes(i: int, h: int, l: int, layers=None) -> tuple[int, int]:
     """RG-LRU delta-gated projection volumes per stack.
 
     Δx-group: the block input gates w_in + w_in_gate, each [D, W]
@@ -129,20 +129,41 @@ def _rglru_volumes(i: int, h: int, l: int) -> tuple[int, int]:
     return 2 * i * h * l, 2 * h * h * l
 
 
+def _mamba2h_volumes(i: int, h: int, l: int, layers) -> tuple[int, int]:
+    """Mamba-2 / attention hybrid delta-gated projection volumes (bf16).
+
+    Δx-group: the mixer input gates ``in_proj`` (Mamba layers) or
+    ``q, k, v`` (attention layers), and the MLP input gates ``W_in``.
+    Δh-group: the mixer's inner stream gates ``out_proj`` / ``o_proj``,
+    and the SwiGLU output gates ``W_out``. The shapes differ by layer
+    kind, so they are read from the compiled stack (``layers``); the
+    conv, the SSM update, the attention over the cache and the tied head
+    stay dense.
+    """
+    from repro.core.deltamamba2h import proj_volumes
+    if layers is None:
+        raise ValueError("mamba2h volumes are read from the compiled "
+                         "stack; pass layers=program.layers")
+    return proj_volumes(layers)
+
+
 # Cell families priced by explicit projection volumes rather than gate
-# rows: maps cell -> fn(input_size, hidden_size, num_layers) ->
-# (x_weights, h_weights).
-CELL_PROJ_VOLUMES = {"rwkv6": _rwkv6_volumes, "rglru": _rglru_volumes}
+# rows: maps cell -> fn(input_size, hidden_size, num_layers, layers) ->
+# (x_weights, h_weights); ``layers`` is the compiled stack, for a family
+# whose volumes its three sizes do not fix.
+CELL_PROJ_VOLUMES = {"rwkv6": _rwkv6_volumes, "rglru": _rglru_volumes,
+                     "mamba2h": _mamba2h_volumes}
 
 
 def cell_dims(cell: str, input_size: int, hidden_size: int,
-              num_layers: int) -> GruDims:
+              num_layers: int, layers=None) -> GruDims:
     """Dims of an L-layer delta-RNN stack of the given cell family."""
     if cell in CELL_GATES:
         return GruDims(input_size, hidden_size, num_layers,
                        gates=CELL_GATES[cell])
     if cell in CELL_PROJ_VOLUMES:
-        xw, hw = CELL_PROJ_VOLUMES[cell](input_size, hidden_size, num_layers)
+        xw, hw = CELL_PROJ_VOLUMES[cell](input_size, hidden_size, num_layers,
+                                         layers)
         return GruDims(input_size, hidden_size, num_layers, gates=1,
                        x_weights=xw, h_weights=hw)
     raise ValueError(f"unknown cell family {cell!r}; known gate "

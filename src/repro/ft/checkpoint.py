@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 Array = jax.Array
@@ -153,6 +154,9 @@ def restore(ckpt_dir: str, target_tree, step: int | None = None,
     for p, tgt, sh in zip(tgt_paths, tgt_leaves, flat_shardings):
         entry = by_path[p]
         arr = np.load(os.path.join(path, entry["file"]))
+        if arr.dtype.kind == "V":
+            # .npy keeps an extension dtype (bfloat16, float8) as raw bytes
+            arr = arr.view(jnp.dtype(entry["dtype"]))
         if verify and _checksum(arr) != entry["sha"]:
             raise IOError(f"checksum mismatch for {p} in {path}")
         tgt_arr = np.asarray(tgt)

@@ -254,7 +254,7 @@ class DeltaStreamEngine:
                                             backend=backend or "fused",
                                             cell=infer_cell(program),
                                             layouts=layouts)
-        if program.head is None:
+        if not program.has_head:
             raise ValueError(
                 "DeltaStreamEngine needs a program with a classifier head; "
                 "compile from an init_gru_model / init_lstm_model params "
@@ -285,7 +285,12 @@ class DeltaStreamEngine:
         self.theta_x = self.thresholds.theta_x
         self.dynamic_target = dynamic_target_fired
         self.dims = cell_dims(program.cell, task.input_size,
-                              task.hidden_size, task.num_layers)
+                              task.hidden_size, task.num_layers,
+                              layers=program.layers)
+        # token frames stay int32 end to end; a family with a KV cache
+        # refuses a token past it (counted here, on the host)
+        self._frame_dtype = program.frame_dtype
+        self._max_context = program.max_context
         # per-layer thresholds ride as static tuples inside the jitted
         # step; the dynamic controller steers ONE scalar theta_h, so the
         # two are mutually exclusive rather than silently combined.
@@ -315,7 +320,7 @@ class DeltaStreamEngine:
                 flags = jnp.maximum(flags, bad.astype(jnp.float32))
             return flags
 
-        def _one_step(state, carry, x):
+        def _one_step(prog, state, carry, x):
             """One timestep, stats + controller on-device (no host sync).
 
             Firing fractions are tracked **per stream** (``[N]`` carry
@@ -332,16 +337,16 @@ class DeltaStreamEngine:
             recurrent state, and the per-slot ``poison_steps`` counter
             records the masking without any host round-trip.
             """
-            finite = jnp.all(jnp.isfinite(x), axis=-1)           # [N]
+            finite = jnp.all(prog.frame_ok(x), axis=-1)         # [N]
             x = jnp.where(finite[:, None], x, carry["last_x"])
             poison = 1.0 - finite.astype(jnp.float32)            # [N]
             tx = (self._theta_x_layers if self._per_layer
                   else self.theta_x)
             th = (self._theta_h_layers if self._per_layer
                   else carry["theta_h"])
-            y, new_state, deltas = self.program.step(state, x, tx, th)
-            bad = _nonfinite_rows(new_state.stack)               # [N]
-            out = y @ self.head[0] + self.head[1]
+            y, new_state, deltas = prog.step(state, x, tx, th)
+            bad = _nonfinite_rows(prog.watched(new_state))       # [N]
+            out = prog.apply_head(y)
             fx = jnp.mean(jnp.stack(
                 [jnp.mean((dx != 0).astype(jnp.float32), axis=-1)
                  for dx, _ in deltas]), axis=0)                   # [N]
@@ -408,13 +413,13 @@ class DeltaStreamEngine:
             }
             return out, new_state, new_carry
 
-        def _step(state, carry, x):
-            return _one_step(state, carry, x)
+        def _step(prog, state, carry, x):
+            return _one_step(prog, state, carry, x)
 
-        def _steps(state, carry, xs):
+        def _steps(prog, state, carry, xs):
             def body(sc, x):
                 state, carry = sc
-                out, state, carry = _one_step(state, carry, x)
+                out, state, carry = _one_step(prog, state, carry, x)
                 return (state, carry), out
 
             (state, carry), outs = jax.lax.scan(body, (state, carry), xs)
@@ -422,10 +427,10 @@ class DeltaStreamEngine:
 
         n = n_streams
 
-        def _reset_streams(state, carry, mask):
+        def _reset_streams(prog, state, carry, mask):
             """Masked per-slot reset: fresh state + zeroed accounting for
             slots where ``mask`` is True; everything else untouched."""
-            fresh = self.program.init_state((n,))
+            fresh = prog.init_state((n,))
 
             def sel(cur, new):
                 m = mask.reshape((n,) + (1,) * (cur.ndim - 1))
@@ -435,16 +440,20 @@ class DeltaStreamEngine:
             carry = dict(carry)
             for k in self._PER_STREAM_KEYS:
                 carry[k] = jnp.where(mask, 0.0, carry[k])
-            carry["last_x"] = jnp.where(mask[:, None], 0.0, carry["last_x"])
+            carry["last_x"] = jnp.where(mask[:, None],
+                                        jnp.zeros((), carry["last_x"].dtype),
+                                        carry["last_x"])
             return state, carry
 
-        def _merge_rows(dst_state, dst_carry, src_state, src_carry, mask):
+        def _merge_rows(prog, dst_state, dst_carry, src_state, src_carry,
+                        mask):
             """Take ``src``'s slot rows where ``mask`` is True, ``dst``'s
             elsewhere — the snapshot/rollback primitive (used in both
             directions). Only the per-stream carry entries move; the
             engine-lifetime aggregates and Θ_h always keep ``dst``'s
             values, so a rollback never un-counts steps that really
             executed and never disturbs the global threshold."""
+            del prog
             def sel(cur, new):
                 m = mask.reshape((n,) + (1,) * (cur.ndim - 1))
                 return jnp.where(m, new, cur)
@@ -463,30 +472,51 @@ class DeltaStreamEngine:
         # per-shard block shapes — per-stream vectors become ``[B]``
         # slices and the lifetime aggregates become ``[1]`` slices of a
         # per-shard vector; the closures are shape-polymorphic in both.
-        self._one_step_fn = _one_step
-        self._steps_fn = _steps
-        self._reset_streams_fn = _reset_streams
-        self._merge_rows_fn = _merge_rows
+        bound = lambda f: functools.partial(f, program)
+        self._one_step_fn = bound(_one_step)
+        self._steps_fn = bound(_steps)
+        self._reset_streams_fn = bound(_reset_streams)
+        self._merge_rows_fn = bound(_merge_rows)
 
         # Batch 1: the ``[I]`` -> ``[1, I]`` reshape and the ``[0]`` slice
         # of the output run inside the compiled step, so a single-stream
         # tick dispatches ONE call (an eager op on the host costs a whole
         # dispatch). ``x`` may be ``[I]`` or ``[1, I]``; ``xs`` is
         # ``[T, I]``.
-        def _step1(state, carry, x):
-            out, state, carry = _one_step(state, carry, x.reshape(1, -1))
+        def _step1(prog, state, carry, x):
+            out, state, carry = _one_step(prog, state, carry,
+                                          x.reshape(1, -1))
             return out[0], state, carry
 
-        def _steps1(state, carry, xs):
-            outs, state, carry = _steps(state, carry, xs[:, None, :])
+        def _steps1(prog, state, carry, xs):
+            outs, state, carry = _steps(prog, state, carry, xs[:, None, :])
             return outs[:, 0], state, carry
 
-        self._step = jax.jit(_step)
-        self._steps = jax.jit(_steps)
-        self._step1 = jax.jit(_step1)
-        self._steps1 = jax.jit(_steps1)
-        self._reset_streams = jax.jit(_reset_streams)
-        self._merge_rows = jax.jit(_merge_rows)
+        if program.in_place:
+            # A family with a KV cache: its weights go in as arguments
+            # (closed over, a multi-GB model would be copied into the
+            # executable as constants) and the state, argument 1, is
+            # donated, so the cache and the SSM state are updated in place
+            # instead of copied every token (on a merge, the destination
+            # rows' state: the shadow on a snapshot, the live state on a
+            # rollback).
+            def jit(f):
+                jf = jax.jit(f, donate_argnums=(1,))
+                return lambda *a: jf(self.program, *a)
+        else:
+            # The GRU / LSTM programs keep their weights closed over, as
+            # constants: passed as arguments, XLA sums their products in
+            # another order, and the outputs move in the last place (the
+            # benchmark's golden digests and the bit-for-bit tests of
+            # tests/test_engine_batch1.py pin them).
+            def jit(f):
+                return jax.jit(bound(f))
+        self._step = jit(_step)
+        self._steps = jit(_steps)
+        self._step1 = jit(_step1)
+        self._steps1 = jit(_steps1)
+        self._reset_streams = jit(_reset_streams)
+        self._merge_rows = jit(_merge_rows)
         self.reset()
 
     # -- hot path ---------------------------------------------------------
@@ -518,8 +548,8 @@ class DeltaStreamEngine:
         """
         with _span("engine.h2d"):
             if isinstance(x, np.ndarray):
-                x = np.array(x, np.float32)
-            x = jnp.asarray(x, jnp.float32)
+                x = np.array(x, self._frame_dtype)
+            x = jnp.asarray(x, self._frame_dtype)
             i_dim = self.dims.input_size
             single = self.n_streams == 1
             if x.shape != (self.n_streams, i_dim) and not (
@@ -532,6 +562,8 @@ class DeltaStreamEngine:
                     f"{f' or [1, {i_dim}]' if single else ''}, "
                     f"got {tuple(x.shape)} — reshaping would silently "
                     "cross-contaminate stream slots")
+        if self._max_context is not None:
+            self._check_context(1)
         with _span("engine.launch"):
             step = self._step1 if single else self._step
             out, self.state, self._carry = step(self.state, self._carry, x)
@@ -550,8 +582,8 @@ class DeltaStreamEngine:
         """
         with _span("engine.h2d"):
             if isinstance(xs, np.ndarray):
-                xs = np.array(xs, np.float32)
-            xs = jnp.asarray(xs, jnp.float32)
+                xs = np.array(xs, self._frame_dtype)
+            xs = jnp.asarray(xs, self._frame_dtype)
             squeeze = xs.ndim == 2
             if squeeze:
                 if self.n_streams != 1:
@@ -564,12 +596,33 @@ class DeltaStreamEngine:
                 raise ValueError(
                     f"chunk stream dim {xs.shape[1]} != n_streams="
                     f"{self.n_streams} (xs: {xs.shape})")
+        if self._max_context is not None:
+            self._check_context(xs.shape[0])
         with _span("engine.launch"):
             steps = self._steps1 if squeeze else self._steps
             outs, self.state, self._carry = steps(self.state, self._carry,
                                                   xs)
         self._n_steps += xs.shape[0]
         return outs
+
+    def _tokens(self, sid: int) -> int:
+        """Frames slot ``sid`` has taken since its session (or the engine)
+        started: for a family with a KV cache, the tokens the cache holds."""
+        return self._n_steps - self._slot_opened_at[sid]
+
+    def _check_context(self, n_new: int) -> None:
+        """Refuse, before any device work, a step that would take an open
+        stream (every slot, where no session is open) past the cache's
+        ``max_context`` tokens: the cache never wraps."""
+        live = [s for s, busy in enumerate(self._slot_busy) if busy] \
+            or range(self.n_streams)
+        for sid in live:
+            if self._tokens(sid) + n_new > self._max_context:
+                raise ValueError(
+                    f"stream slot {sid} holds {self._tokens(sid)} tokens; "
+                    f"{n_new} more would pass its KV cache of "
+                    f"{self._max_context} positions (max_context), which "
+                    "does not wrap")
 
     # -- per-stream sessions ----------------------------------------------
 
@@ -619,13 +672,13 @@ class DeltaStreamEngine:
             raise ValueError(f"stream {sid} is not open")
         host = host_carry if host_carry is not None \
             else jax.device_get(self._carry)
-        steps = self._n_steps - self._slot_opened_at[sid]
+        steps = self._tokens(sid)
         fired_x = float(host["fired_x"][sid])
         fired_h = float(host["fired_h"][sid])
         lat = float(host["lat_s"][sid])
         wb = float(host["w_bytes"][sid])
         self._slot_busy[sid] = False
-        return {
+        out = {
             "stream": sid,
             "steps": steps,
             "gamma_dx": 1.0 - fired_x / max(steps, 1),
@@ -637,6 +690,9 @@ class DeltaStreamEngine:
             "poison_steps": float(host["poison_steps"][sid]),
             "bad_state_steps": float(host["bad_state"][sid]),
         }
+        if self._max_context is not None:
+            out["kv_len"] = steps
+        return out
 
     # -- resilience: snapshot / rollback / checkpoint ----------------------
 
@@ -660,7 +716,7 @@ class DeltaStreamEngine:
                 raise ValueError(f"stream {sid} out of range")
             mask[sid] = True
         self._snap_state, self._snap_carry = self._merge_rows(
-            self._snap_state, self._snap_carry, self.state, self._carry,
+            self._shadow(), self._snap_carry, self.state, self._carry,
             jnp.asarray(mask))
         for sid in sids:
             self._snap_steps[sid] = self._n_steps - self._slot_opened_at[sid]
@@ -679,7 +735,7 @@ class DeltaStreamEngine:
         mask = np.zeros((self.n_streams,), bool)
         mask[sid] = True
         self.state, self._carry = self._merge_rows(
-            self.state, self._carry, self._snap_state, self._snap_carry,
+            self.state, self._carry, self._shadow(), self._snap_carry,
             jnp.asarray(mask))
         # the slot has logically executed only _snap_steps[sid] session
         # steps again; engine-global _n_steps keeps marching, so rebase
@@ -702,13 +758,23 @@ class DeltaStreamEngine:
                 "silently override the per-layer threshold policy")
         self._carry = {**self._carry, "theta_h": jnp.float32(value)}
 
+    def _shadow(self):
+        """The rollback shadow of the state. An in-place engine mints it on
+        first use: until a snapshot is taken it holds fresh state, and a
+        shadow that aliased the donated live state would be freed by the
+        next step."""
+        if self._snap_state is None:
+            self._snap_state = self.program.init_state(
+                batch_shape=(self.n_streams,))
+        return self._snap_state
+
     def _ckpt_tree(self):
         """The engine's full restorable pytree (state + carry + shadows +
         host-side slot bookkeeping as numpy leaves)."""
         return {
             "state": self.state,
             "carry": self._carry,
-            "snap_state": self._snap_state,
+            "snap_state": self._shadow(),
             "snap_carry": self._snap_carry,
             "meta": {
                 "n_steps": np.int64(self._n_steps),
@@ -800,7 +866,7 @@ class DeltaStreamEngine:
             "agg_tile_lat_s": jnp.float32(0.0),
             "agg_tile_w_bytes": jnp.float32(0.0),
             "last_x": jnp.zeros((self.n_streams, self.dims.input_size),
-                                jnp.float32),
+                                self._frame_dtype),
             "poison_steps": zeros,
             "bad_state": zeros,
             "agg_poison_steps": jnp.float32(0.0),
@@ -813,7 +879,7 @@ class DeltaStreamEngine:
         # snapshot shadows (device-resident): rollback targets per slot.
         # _snap_steps[sid] = session-steps already executed at snapshot
         # time, so a rollback can rewind the slot's step bookkeeping too.
-        self._snap_state = self.state
+        self._snap_state = None if self.program.in_place else self.state
         self._snap_carry = dict(self._carry)
         self._snap_steps = [0] * self.n_streams
 
@@ -848,6 +914,9 @@ class DeltaStreamEngine:
             rep["tile_weight_bytes_per_step"] = s.tile_w_bytes / steps
             rep["weight_bytes_per_stream_per_step"] = (
                 s.tile_w_bytes / steps / self.n_streams)
+        if self._max_context is not None:
+            rep["kv_len"] = [self._tokens(s) for s in range(self.n_streams)]
+            rep["max_context"] = self._max_context
         if self._per_layer:
             # the scalar fields would report the (unapplied) global policy
             # values — under a per-layer policy the tuples are the truth

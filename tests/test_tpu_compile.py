@@ -126,3 +126,48 @@ def test_cell_step_compiles_for_v5e(one_chip, case):
 def test_lm_kernel_compiles_for_v5e(one_chip, case):
     fn, args = LM_CASES[case]
     assert "tpu_custom_call" in _compile_on(one_chip, fn, args)
+
+
+def test_granite_decode_step_compiles_in_place_for_v5e(one_chip):
+    """The whole granite-4.0-h-micro decode step (40 layers at hidden
+    2048, the bf16 KV cache at 131,072 positions) with the state donated:
+    the cache and the SSM state alias their outputs, no operation copies
+    either, and the step needs only a few MB beside its arguments."""
+    import re
+
+    from repro.core.deltamamba2h import (Mamba2hConfig,
+                                         deltamamba2h_stack_step,
+                                         init_mamba2h_params,
+                                         init_mamba2h_stack_state,
+                                         mamba2h_head)
+    types = ["mamba"] * 40
+    for i in (5, 15, 25, 35):
+        types[i] = "attention"
+    cfg = Mamba2hConfig(tuple(types), d_model=2048, vocab=100352,
+                        mamba_heads=64, mamba_head_dim=64, d_state=128,
+                        n_groups=1, d_conv=4, attn_heads=32, kv_heads=8,
+                        attn_head_dim=64, intermediate=8192,
+                        max_context=131072)
+    place = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = place(jax.eval_shape(
+        lambda: init_mamba2h_params(jax.random.PRNGKey(0), cfg)))
+    state = place(jax.eval_shape(
+        lambda: init_mamba2h_stack_state(params, (1,))))
+
+    def step(params, state, tok):
+        y, state, _ = deltamamba2h_stack_step((params,), state, tok, 0.0,
+                                              0.0)
+        return mamba2h_head((params,), y), state
+
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    c = jax.jit(step, donate_argnums=(1,)).lower(params, state, tok).compile()
+    text = c.as_text()
+    big = (r"bf16\[1,4,131072,512\]", r"f32\[1,36,64,64,128\]")
+    for shape in big:
+        assert not re.search(rf"= {shape}\S* copy(-start)?\(", text), shape
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 4 * 131072 * 512 * 2 \
+        + 36 * 64 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
