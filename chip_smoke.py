@@ -122,7 +122,7 @@ def phase_stream(params, task, backend: str, frames,
     prog = compile_delta_program(params, backend=backend,
                                  interpret=interpret)
     got, compile_s, eng = _stream_once(prog, task, frames, thresholds)
-    kernel = _has_kernel(eng._step1, eng.state, eng._carry,
+    kernel = _has_kernel(eng._step1, eng._whole, eng._bufs,
                          np.asarray(frames[0]))
     if backend == "fused":
         dense = compile_delta_program(params, backend="dense")
@@ -162,7 +162,7 @@ def phase_batcher(params, task, streams, n_streams: int = 8,
     prog = compile_delta_program(params, backend="fused_q8",
                                  interpret=interpret)
     got, compile_s, eng = _drain(prog, task, streams, n_streams, thresholds)
-    kernel = _has_kernel(eng._step, eng.state, eng._carry,
+    kernel = _has_kernel(eng._step, eng._whole, eng._bufs,
                          np.zeros((n_streams, task.input_size), np.float32))
     with oracle_route():
         want, _, _ = _drain(prog, task, streams, n_streams, thresholds)

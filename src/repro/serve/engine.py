@@ -50,7 +50,13 @@ jitted step as a device carry — nothing forces a host round-trip until
 :attr:`stats` or :meth:`report` is read, and those materialize the carry
 exactly once. (The seed called ``float(fx)``/``float(fh)`` and a host-side
 ``estimate_stack`` every timestep: three blocking transfers per frame,
-which capped streaming throughput at Python-dispatch rate.)
+which capped streaming throughput at Python-dispatch rate.) The state and
+carry cross each compiled call packed, one flat buffer per dtype, since
+the host pays per buffer passed: a batch-1 GRU/LSTM tick moves four (the
+frame and the packed buffer in, the output and the packed buffer out;
+``report()["step_buffers"]``). ``state`` and ``_carry`` read and assign
+the unpacked pytrees, and :meth:`host_carry` reads the carry with one
+transfer.
 
 Because the streams are long-lived and the state is recurrent, the engine
 also carries a **resilience layer** (all device-side, zero-sync like the
@@ -79,6 +85,7 @@ deterministic chaos harness that exercises them.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -103,6 +110,89 @@ Array = jax.Array
 # Host spans of the engine tick, written into the profiler's trace (on the
 # device ops' clock) and nearly free when no profiler is collecting.
 _span = jax.profiler.TraceAnnotation
+
+
+class _FlatLayout:
+    """A static layout of a pytree as one flat 1-D buffer per dtype.
+
+    Buffers follow the order in which their dtypes first appear among the
+    leaves. A buffer starts with the leaves that lead with the stream
+    axis (``n`` rows), as the row-major ``[n, width]`` block of their
+    ``[n, -1]`` views side by side; the other leaves (the scalar
+    aggregates) follow it raveled. ``pack`` and ``unpack`` are data
+    movement only (concatenates, static slices and reshapes), so they run
+    inside a compiled call or, on host numpy buffers, without one.
+
+    The block keeps each leaf's ``[n, -1]`` view whole: XLA's CPU fusion
+    then plans the step's own ops as it does with no pack at all. Raveled
+    leaves gave it reshapes to fuse into, where it recomputed some values
+    twice, once for the output and once for a later op, and the two
+    copies could round apart (the LSTM ``fused`` oracle moved in the last
+    place).
+    """
+
+    def __init__(self, tree, n: int):
+        leaves, self.treedef = jax.tree_util.tree_flatten(tree)
+        self.n = n
+        self.dtypes: list = []
+        self.widths: list = []     # per buffer: the block's width
+        self.slots: list = []      # per leaf: (buffer, in block?, offset)
+        self.shapes = [tuple(leaf.shape) for leaf in leaves]
+        tails: list = []           # per buffer: the raveled leaves' size
+        for leaf, shape in zip(leaves, self.shapes):
+            dtype = jnp.dtype(leaf.dtype)
+            if dtype not in self.dtypes:
+                self.dtypes += [dtype]
+                self.widths += [0]
+                tails += [0]
+            b = self.dtypes.index(dtype)
+            size = math.prod(shape)
+            if shape[:1] == (n,):
+                self.slots.append((b, True, self.widths[b]))
+                self.widths[b] += size // n
+            else:
+                self.slots.append((b, False, tails[b]))
+                tails[b] += size
+
+    def pack(self, tree) -> tuple:
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        if treedef != self.treedef:
+            raise ValueError(f"tree {treedef} does not match the engine's "
+                             f"layout {self.treedef}")
+        rows = [[] for _ in self.dtypes]
+        tails = [[] for _ in self.dtypes]
+        for leaf, (b, row, _), shape in zip(leaves, self.slots,
+                                            self.shapes):
+            leaf = jnp.asarray(leaf)
+            if (tuple(leaf.shape) != shape
+                    or jnp.dtype(leaf.dtype) != self.dtypes[b]):
+                raise ValueError(
+                    f"leaf {leaf.dtype}{list(leaf.shape)} does not match "
+                    f"the engine's layout {self.dtypes[b]}{list(shape)}")
+            if row:
+                rows[b].append(leaf.reshape(self.n, -1))
+            else:
+                tails[b].append(leaf.reshape(-1))
+        return tuple(
+            jnp.concatenate(([jnp.concatenate(r, axis=1).reshape(-1)]
+                             if r else []) + t)
+            for r, t in zip(rows, tails))
+
+    def unpack(self, bufs):
+        """The tree of ``bufs``: device arrays inside a trace, or the host
+        numpy buffers of one ``device_get``."""
+        n = self.n
+        leaves = []
+        for (b, row, off), shape in zip(self.slots, self.shapes):
+            size = math.prod(shape)
+            if row:
+                block = bufs[b][:n * self.widths[b]].reshape(n, -1)
+                leaf = block[:, off:off + size // n]
+            else:
+                start = n * self.widths[b] + off
+                leaf = bufs[b][start:start + size]
+            leaves.append(leaf.reshape(shape))
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
 class LmEngine:
@@ -182,6 +272,22 @@ class StreamStats:
     @property
     def union_gamma_dh(self) -> float:
         return 1.0 - self.ufired_h / max(self.steps, 1)
+
+
+def _held_part(whole: str, bufs: str, part: int) -> property:
+    """Part ``part`` (0: state, 1: carry) of the held pair kept in the
+    attributes ``whole`` / ``bufs``, read and assigned as its pytree: a
+    read unpacks the buffers, an assignment packs them again."""
+    def get(self):
+        return self._view(getattr(self, whole), getattr(self, bufs))[part]
+
+    def put(self, value):
+        pair = list(self._view(getattr(self, whole), getattr(self, bufs)))
+        pair[part] = value
+        w, b = self._hold(*pair)
+        setattr(self, whole, w)
+        setattr(self, bufs, b)
+    return property(get, put)
 
 
 class DeltaStreamEngine:
@@ -492,10 +598,44 @@ class DeltaStreamEngine:
             outs, state, carry = _steps(prog, state, carry, xs[:, None, :])
             return outs[:, 0], state, carry
 
+        # The engine holds its state and carry as a pair ``(whole, bufs)``
+        # and the compiled calls take and return that pair: a call costs
+        # the host a fixed time per argument and result buffer (~70 us on
+        # a TPU v5e host, PERF.md §5), far more than a batch-1 step's
+        # device work, so ``bufs`` packs the leaves into one flat buffer
+        # per dtype, unpacked and packed again inside the call. A family
+        # with a KV cache (``in_place``) keeps its state leaves ``whole``,
+        # donated and updated in place (a concatenate would copy the cache
+        # every token), and packs only the carry; elsewhere ``whole`` is
+        # None.
+        self._packs_state = not program.in_place
+        fresh = jax.eval_shape(lambda: (
+            program.init_state(batch_shape=(n,)), self._fresh_carry()))
+        layout = _FlatLayout(fresh if self._packs_state else fresh[1],
+                             n)
+        self._layout = layout
+        self._pack = jax.jit(layout.pack)
+        self._unpack = jax.jit(layout.unpack)
+
+        def held(f):
+            """``f(prog, state, carry, *a) -> (*outs, state, carry)`` on
+            the held pair: ``(prog, whole, bufs, *a) -> (*outs, whole,
+            bufs)``."""
+            def g(prog, whole, bufs, *a):
+                *outs, state, carry = f(prog, *self._view(whole, bufs), *a)
+                return (*outs, *self._hold(state, carry))
+            return g
+
+        def _merge_held(prog, dst_whole, dst_bufs, src_whole, src_bufs,
+                        mask):
+            return self._hold(*_merge_rows(
+                prog, *self._view(dst_whole, dst_bufs),
+                *self._view(src_whole, src_bufs), mask))
+
         if program.in_place:
             # A family with a KV cache: its weights go in as arguments
             # (closed over, a multi-GB model would be copied into the
-            # executable as constants) and the state, argument 1, is
+            # executable as constants) and its whole state, argument 1, is
             # donated, so the cache and the SSM state are updated in place
             # instead of copied every token (on a merge, the destination
             # rows' state: the shadow on a snapshot, the live state on a
@@ -511,13 +651,39 @@ class DeltaStreamEngine:
             # tests/test_engine_batch1.py pin them).
             def jit(f):
                 return jax.jit(bound(f))
-        self._step = jit(_step)
-        self._steps = jit(_steps)
-        self._step1 = jit(_step1)
-        self._steps1 = jit(_steps1)
-        self._reset_streams = jit(_reset_streams)
-        self._merge_rows = jit(_merge_rows)
+        self._step = jit(held(_step))
+        self._steps = jit(held(_steps))
+        self._step1 = jit(held(_step1))
+        self._steps1 = jit(held(_steps1))
+        self._reset_streams = jit(held(_reset_streams))
+        self._merge_rows = jit(_merge_held)
         self.reset()
+
+    # -- the held state ---------------------------------------------------
+
+    state = _held_part("_whole", "_bufs", 0)
+    _carry = _held_part("_whole", "_bufs", 1)
+    _snap_state = _held_part("_snap_whole", "_snap_bufs", 0)
+    _snap_carry = _held_part("_snap_whole", "_snap_bufs", 1)
+
+    def _view(self, whole, bufs):
+        """The ``(state, carry)`` pytrees of a held pair; inside a trace
+        the unpack is inlined, outside it is one compiled call."""
+        held = self._unpack(bufs)
+        return held if self._packs_state else (whole, held)
+
+    def _hold(self, state, carry):
+        """The held pair ``(whole, bufs)`` of a ``(state, carry)``."""
+        if self._packs_state:
+            return None, self._pack((state, carry))
+        return state, self._pack(carry)
+
+    def host_carry(self) -> dict:
+        """The live carry on the host as numpy arrays: ONE ``device_get``
+        of the packed buffers (the state's part too, where it is packed),
+        split by the static layout with no further device call."""
+        held = self._layout.unpack(jax.device_get(self._bufs))
+        return held[1] if self._packs_state else held
 
     # -- hot path ---------------------------------------------------------
 
@@ -566,7 +732,7 @@ class DeltaStreamEngine:
             self._check_context(1)
         with _span("engine.launch"):
             step = self._step1 if single else self._step
-            out, self.state, self._carry = step(self.state, self._carry, x)
+            out, self._whole, self._bufs = step(self._whole, self._bufs, x)
         self._n_steps += 1
         return out
 
@@ -600,7 +766,7 @@ class DeltaStreamEngine:
             self._check_context(xs.shape[0])
         with _span("engine.launch"):
             steps = self._steps1 if squeeze else self._steps
-            outs, self.state, self._carry = steps(self.state, self._carry,
+            outs, self._whole, self._bufs = steps(self._whole, self._bufs,
                                                   xs)
         self._n_steps += xs.shape[0]
         return outs
@@ -649,8 +815,8 @@ class DeltaStreamEngine:
         sid = free[0]
         mask = np.zeros((self.n_streams,), bool)
         mask[sid] = True
-        self.state, self._carry = self._reset_streams(
-            self.state, self._carry, jnp.asarray(mask))
+        self._whole, self._bufs = self._reset_streams(
+            self._whole, self._bufs, jnp.asarray(mask))
         self._slot_busy[sid] = True
         self._slot_opened_at[sid] = self._n_steps
         # seed the slot's rollback target with its fresh session state, so
@@ -665,13 +831,12 @@ class DeltaStreamEngine:
         One host sync (the per-stream carry vectors materialize once).
         The slot is immediately reusable by the next :meth:`open_stream`.
         ``host_carry`` lets a scheduler harvesting several streams in one
-        tick fetch the carry once (``jax.device_get(engine._carry)``) and
-        share it across the closes instead of syncing per stream.
+        tick fetch the carry once (:meth:`host_carry`) and share it
+        across the closes instead of syncing per stream.
         """
         if not (0 <= sid < self.n_streams) or not self._slot_busy[sid]:
             raise ValueError(f"stream {sid} is not open")
-        host = host_carry if host_carry is not None \
-            else jax.device_get(self._carry)
+        host = host_carry if host_carry is not None else self.host_carry()
         steps = self._tokens(sid)
         fired_x = float(host["fired_x"][sid])
         fired_h = float(host["fired_h"][sid])
@@ -715,8 +880,8 @@ class DeltaStreamEngine:
             if not (0 <= sid < self.n_streams):
                 raise ValueError(f"stream {sid} out of range")
             mask[sid] = True
-        self._snap_state, self._snap_carry = self._merge_rows(
-            self._shadow(), self._snap_carry, self.state, self._carry,
+        self._snap_whole, self._snap_bufs = self._merge_rows(
+            self._shadow(), self._snap_bufs, self._whole, self._bufs,
             jnp.asarray(mask))
         for sid in sids:
             self._snap_steps[sid] = self._n_steps - self._slot_opened_at[sid]
@@ -734,8 +899,8 @@ class DeltaStreamEngine:
             raise ValueError(f"stream {sid} is not open")
         mask = np.zeros((self.n_streams,), bool)
         mask[sid] = True
-        self.state, self._carry = self._merge_rows(
-            self.state, self._carry, self._shadow(), self._snap_carry,
+        self._whole, self._bufs = self._merge_rows(
+            self._whole, self._bufs, self._shadow(), self._snap_bufs,
             jnp.asarray(mask))
         # the slot has logically executed only _snap_steps[sid] session
         # steps again; engine-global _n_steps keeps marching, so rebase
@@ -759,23 +924,25 @@ class DeltaStreamEngine:
         self._carry = {**self._carry, "theta_h": jnp.float32(value)}
 
     def _shadow(self):
-        """The rollback shadow of the state. An in-place engine mints it on
-        first use: until a snapshot is taken it holds fresh state, and a
-        shadow that aliased the donated live state would be freed by the
-        next step."""
-        if self._snap_state is None:
-            self._snap_state = self.program.init_state(
+        """The whole state of the rollback shadow (None where the state is
+        packed). An in-place engine mints it on first use: until a
+        snapshot is taken it holds fresh state, and a shadow that aliased
+        the donated live state would be freed by the next step."""
+        if self._snap_whole is None and not self._packs_state:
+            self._snap_whole = self.program.init_state(
                 batch_shape=(self.n_streams,))
-        return self._snap_state
+        return self._snap_whole
 
     def _ckpt_tree(self):
         """The engine's full restorable pytree (state + carry + shadows +
-        host-side slot bookkeeping as numpy leaves)."""
+        host-side slot bookkeeping as numpy leaves), unpacked."""
+        state, carry = self._view(self._whole, self._bufs)
+        snap_state, snap_carry = self._view(self._shadow(), self._snap_bufs)
         return {
-            "state": self.state,
-            "carry": self._carry,
-            "snap_state": self._shadow(),
-            "snap_carry": self._snap_carry,
+            "state": state,
+            "carry": carry,
+            "snap_state": snap_state,
+            "snap_carry": snap_carry,
             "meta": {
                 "n_steps": np.int64(self._n_steps),
                 "slot_busy": np.asarray(self._slot_busy, bool),
@@ -806,10 +973,9 @@ class DeltaStreamEngine:
         """
         eng = cls(program, task, **kwargs)
         tree = ft_checkpoint.restore(ckpt_dir, eng._ckpt_tree(), step=step)
-        eng.state = tree["state"]
-        eng._carry = tree["carry"]
-        eng._snap_state = tree["snap_state"]
-        eng._snap_carry = tree["snap_carry"]
+        eng._whole, eng._bufs = eng._hold(tree["state"], tree["carry"])
+        eng._snap_whole, eng._snap_bufs = eng._hold(tree["snap_state"],
+                                                    tree["snap_carry"])
         meta = jax.device_get(tree["meta"])
         eng._n_steps = int(meta["n_steps"])
         eng._slot_busy = [bool(b) for b in meta["slot_busy"]]
@@ -822,7 +988,7 @@ class DeltaStreamEngine:
     @property
     def theta_h(self) -> float:
         """Current Θ_h (syncs once; moves only under the dynamic controller)."""
-        return float(self._carry["theta_h"])
+        return float(self.host_carry()["theta_h"])
 
     @property
     def stats(self) -> StreamStats:
@@ -834,7 +1000,7 @@ class DeltaStreamEngine:
         linear in the firing fractions, so the stream mean reproduces the
         single-stream accounting exactly.
         """
-        host = jax.device_get(self._carry)
+        host = self.host_carry()
         return StreamStats(
             steps=self._n_steps,
             fired_x=float(host["agg_fired_x"]),
@@ -849,10 +1015,9 @@ class DeltaStreamEngine:
             bad_state_steps=float(host["agg_bad_state"]),
         )
 
-    def reset(self):
-        self.state = self.program.init_state(batch_shape=(self.n_streams,))
+    def _fresh_carry(self) -> dict:
         zeros = jnp.zeros((self.n_streams,), jnp.float32)
-        self._carry = {
+        return {
             "fired_x": zeros,
             "fired_h": zeros,
             "lat_s": zeros,
@@ -873,15 +1038,31 @@ class DeltaStreamEngine:
             "agg_bad_state": jnp.float32(0.0),
             "theta_h": jnp.float32(self.thresholds.theta_h),
         }
+
+    def reset(self):
+        self._whole, self._bufs = self._hold(
+            self.program.init_state(batch_shape=(self.n_streams,)),
+            self._fresh_carry())
         self._n_steps = 0
         self._slot_busy = [False] * self.n_streams
         self._slot_opened_at = [0] * self.n_streams
         # snapshot shadows (device-resident): rollback targets per slot.
         # _snap_steps[sid] = session-steps already executed at snapshot
         # time, so a rollback can rewind the slot's step bookkeeping too.
-        self._snap_state = None if self.program.in_place else self.state
-        self._snap_carry = dict(self._carry)
+        # An in-place engine's whole state is donated, so its shadow is
+        # minted apart (_shadow); packed buffers are never donated.
+        self._snap_whole = None
+        self._snap_bufs = self._bufs
         self._snap_steps = [0] * self.n_streams
+
+    def _step_buffers(self) -> int:
+        """Argument and result buffers of one compiled tick: the frame and
+        the held pair in (and the weights, where they are arguments), the
+        output and the held pair out."""
+        held = len(jax.tree_util.tree_leaves(self._whole)) + len(self._bufs)
+        weights = (len(jax.tree_util.tree_leaves(self.program))
+                   if self.program.in_place else 0)
+        return weights + 2 * (1 + held)
 
     def report(self) -> dict:
         s = self.stats
@@ -902,6 +1083,7 @@ class DeltaStreamEngine:
             "weight_fetch": "tile" if self._tile_fetch else "stream",
             "poison_steps": s.poison_steps,
             "bad_state_steps": s.bad_state_steps,
+            "step_buffers": self._step_buffers(),
         }
         if self._tile_fetch:
             # the batched-tile economics: ONE weight pass per step serves

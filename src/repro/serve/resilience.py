@@ -330,7 +330,7 @@ class ResilientStreamServer:
     def _check_tick(self) -> list[ServeResult]:
         p = self.policy
         out: list[ServeResult] = []
-        host = jax.device_get(self.engine._carry)
+        host = self.engine.host_carry()
         healthy = []
         for sid, req in enumerate(self.batcher.slots):
             if req is None:
@@ -495,7 +495,7 @@ def serve_resumable(program, task, arrivals, policy: ResiliencePolicy, *,
             # in-flight slots lost their host-side request state with the
             # crash: close them out (their executed steps stay in the
             # lifetime aggregates) and replay those arrivals from scratch
-            host = jax.device_get(engine._carry)
+            host = engine.host_carry()
             for sid in range(engine.n_streams):
                 if engine._slot_busy[sid]:
                     engine.close_stream(sid, host_carry=host)

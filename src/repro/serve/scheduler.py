@@ -307,7 +307,7 @@ class GruStreamBatcher:
         if finished:
             with _span("batcher.harvest"):
                 # one sync per tick, shared by every stream that finished
-                host_carry = jax.device_get(self.engine._carry)
+                host_carry = self.engine.host_carry()
                 for sid, req in finished:
                     req.stats = self.engine.close_stream(
                         sid, host_carry=host_carry)

@@ -166,6 +166,33 @@ def test_token_past_max_context_is_refused(prog):
     assert eng.state is state                  # refused before the device
 
 
+def test_engine_keeps_the_state_whole_and_donated(prog):
+    """A family with a KV cache packs only its carry (one buffer per dtype:
+    float32 and the int32 frame memory); its state leaves cross the step
+    whole and donated, so the cache's buffer is reused from tick to
+    tick."""
+    eng = _engine(prog)
+    toks = _tokens(3, seed=8)
+    eng.step(toks[0])
+    state = eng.state
+    assert [b.dtype for b in eng._bufs] == [jnp.float32, jnp.int32]
+    assert eng._layout.treedef == jax.tree_util.tree_structure(eng._carry)
+    k = state.stack.k
+    ptr = k.unsafe_buffer_pointer()
+    eng.step(toks[1])
+    assert k.is_deleted()
+    assert eng.state.stack.k.unsafe_buffer_pointer() == ptr
+    leaves = jax.tree_util.tree_leaves
+    held = len(leaves(eng.state)) + 2
+    assert eng.report()["step_buffers"] == len(leaves(prog)) + 2 * (1 + held)
+    host = eng.host_carry()
+    want = jax.device_get(eng._carry)
+    assert host.keys() == want.keys()
+    for key in want:
+        assert host[key].dtype == want[key].dtype
+        np.testing.assert_array_equal(host[key], want[key])
+
+
 def test_int32_ids_survive_the_engine(prog):
     eng = _engine(prog)
     toks = _tokens(6, seed=6)
