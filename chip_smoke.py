@@ -14,8 +14,9 @@ One chip runs two phases:
 (a) one stream of ``FRAMES`` frames through ``DeltaStreamEngine.step`` and
     ``step_many``, for each of ``fused``, ``fused_q8`` and ``fused_q4``;
 (b) ``N_STREAMS_B`` seeded streams of 20-200 frames through a
-    ``DeltaStreamBatcher`` on an ``n_streams=8`` ``fused_q8`` engine, which
-    routes itself onto the ``fused_q8_batch`` tile kernel.
+    ``DeltaStreamBatcher`` on an ``n_streams=8`` ``fused_q8`` engine, whose
+    kernel serves the 8-stream tile in one weight pass (``weight_fetch``
+    ``"tile"`` in its report).
 
 ``--fleet`` runs only (c): a ``ShardedStreamFleet`` of ``n_streams=32``
 ``fused_q8`` slots on a 4-way ``data`` mesh behind a ``StreamRouter``,
@@ -168,14 +169,17 @@ def phase_batcher(params, task, streams, n_streams: int = 8,
         want, _, _ = _drain(prog, task, streams, n_streams, thresholds)
     diff = max(float(np.max(np.abs(g - w))) for g, w in zip(got, want))
     finite = all(np.isfinite(g).all() for g in got)
-    return {"phase": "batcher", "backend": eng.backend,
+    rep = eng.report()
+    return {"phase": "batcher", "backend": rep["backend"],
+            "weight_fetch": rep["weight_fetch"],
             "n_streams": n_streams, "streams": len(streams),
             "frames": int(sum(len(s) for s in streams)),
             "compile_s": compile_s, "reference": "jnp oracle",
             "max_abs_diff": diff, "tol": 0.0, "finite": bool(finite),
             "tpu_custom_call": kernel,
             "ok": bool(finite and diff == 0.0
-                       and eng.backend == "fused_q8_batch")}
+                       and rep["backend"] == "fused_q8"
+                       and rep["weight_fetch"] == "tile")}
 
 
 def phase_fleet(params, task, arrivals, n_devices: int = FLEET_DEVICES,

@@ -30,8 +30,9 @@ def reduced_delta_recipe(key, output_size: int = 48):
     the RECURRENT layers of the reduced block pattern (the delta serving
     stack holds only the RG-LRU blocks; attention layers are not delta
     targets), and the matching ``GruTaskConfig`` for
-    ``DeltaStreamEngine``. ``benchmarks.lm_delta_bench`` builds from
-    this, so CI runs the same reduced geometry everywhere.
+    ``DeltaStreamEngine``.
+    ``tests/test_deltarglru.py::TestEngine::test_reduced_recipe_theta0_exact``
+    (theta=0 fires nothing and prices the dense volume) builds from this.
     """
     from repro.core.deltarglru import init_deltarglru_model
     from repro.models.gru_rnn import GruTaskConfig

@@ -28,9 +28,10 @@ def reduced_delta_recipe(key, output_size: int = 48):
     :func:`repro.core.deltarwkv.init_deltarwkv_model` params dict sized
     off it (compile with ``compile_delta_program(model, cell="rwkv6")``),
     and the matching ``GruTaskConfig`` for ``DeltaStreamEngine``. The
-    example (``examples/lm_delta_decode.py``) and the
-    ``benchmarks.lm_delta_bench`` sweep both build from this, so CI runs
-    the same reduced geometry everywhere.
+    example (``examples/lm_delta_decode.py``) and
+    ``tests/test_deltarwkv.py::TestEngine::test_reduced_recipe_theta0_exact``
+    (theta=0 fires nothing and prices the dense volume) both build from
+    this.
     """
     from repro.core.deltarwkv import init_deltarwkv_model
     from repro.models.gru_rnn import GruTaskConfig

@@ -1,9 +1,9 @@
 """The paper's primary contribution: the delta-network (DeltaGRU) algorithm,
 its generalization to arbitrary streamed linear layers, temporal-sparsity
 accounting, threshold policies, and the EdgeDRNN analytical perf model."""
-from repro.core.backends import (BackendSpec, backend_names, get_backend,
-                                 list_backends, register_backend,
-                                 registered_backends, unregister_backend)
+from repro.core.backends import (BackendSpec, get_backend, list_backends,
+                                 register_backend, registered_backends,
+                                 unregister_backend)
 from repro.core.delta import (DeltaState, delta_encode, delta_encode_sequence,
                               delta_encode_ste, init_delta_state,
                               reconstruct_from_deltas)
@@ -21,13 +21,11 @@ from repro.core.deltalstm import (DeltaLstmStackState, LstmLayerParams,
                                   init_deltalstm_state, init_lstm_layer,
                                   init_lstm_stack, lstm_sequence,
                                   lstm_stack_m_init, pack_lstm_stack)
-from repro.core.perf_model import (EDGEDRNN, V5E, AcceleratorSpec,
-                                   TpuChipSpec, batch_sweep,
+from repro.core.perf_model import (EDGEDRNN, AcceleratorSpec,
                                    delta_unit_latency_cycles,
                                    dram_traffic_bytes_per_timestep,
                                    estimate_stack,
-                                   normalized_batch1_throughput,
-                                   tpu_batch1_gru_roofline)
+                                   normalized_batch1_throughput)
 from repro.core.program import (DeltaGruProgram, DeltaGruProgramState,
                                 DeltaProgram, DeltaProgramState,
                                 compile_delta_program, compile_deltagru,

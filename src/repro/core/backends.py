@@ -17,17 +17,20 @@ by hand through ``backend=`` / ``layouts=`` / ``packs=`` /
   :func:`repro.core.perf_model.spec_for_backend`),
 * whether it can run user-supplied activation functions
   (``supports_custom_acts`` — the fused kernels hard-code the Fig. 7
-  pipeline).
+  pipeline),
+* whether its kernel compacts fired blocks on the union across its
+  leading stream axis (``union_compaction``), so one weight pass serves a
+  whole tile of streams.
 
 The registry is keyed on ``(cell, name)`` so it is cell-agnostic: the
 DeltaGRU backends register themselves when :mod:`repro.core.deltagru`
 imports, and :mod:`repro.core.deltalstm` registers the same names under
 ``cell="lstm"``. Lookups lazily import the builtin cell modules, so
 ``get_backend("fused")`` works without the caller having touched
-``deltagru`` first. Each cell family carries batched multi-stream
-variants (``fused_batch`` / ``fused_q8_batch``) whose
-``weight_fetch="tile"`` marks the one-weight-pass-per-stream-tile
-economics the serving engine routes onto when ``n_streams > 1``.
+``deltagru`` first. A backend is registered once per cell: whether a call
+runs one stream or a tile of them is the shape of its input, and the
+serving engine prices a tile (one weight pass at the union firing) from
+its own ``n_streams`` and the spec's ``union_compaction``.
 
 :func:`repro.core.program.compile_delta_program` builds on this: it
 resolves a spec once for any cell family, packs once, and returns a
@@ -48,7 +51,7 @@ class BackendSpec:
     """One execution path for a delta-RNN cell.
 
     Attributes:
-      name: registry key (``"dense" | "fused" | "fused_batch" | ...``).
+      name: registry key (``"dense" | "fused" | "fused_q8" | ...``).
       cell: which recurrent cell family the spec executes (``"gru"``,
         ``"lstm"``, ...). Specs of different cells never collide.
       pack: ``pack(layer_params, block) -> (layers, layouts, packs)`` —
@@ -73,13 +76,13 @@ class BackendSpec:
         model derives K (PE count) and DRAM traffic from it.
       supports_custom_acts: whether user ``sigmoid=`` / ``tanh=``
         overrides are honoured (kernel backends hard-code Fig. 7).
-      weight_fetch: DRAM weight-traffic granularity the Eq. 7 bytes model
-        prices. ``"stream"`` — one weight-volume fetch per stream per
-        step (the batch-1 EdgeDRNN economics; N streams pay N fetches).
-        ``"tile"`` — the batched kernels: one fetch serves the whole
-        ``[B, ...]`` stream tile, compacted on the **union** of fired
-        columns across the tile, so bytes/stream falls sublinearly with B
-        (see :func:`repro.core.perf_model.tile_dram_traffic_bytes_per_timestep`).
+      union_compaction: whether the kernel compacts fired blocks on the
+        **union** of fired columns across its leading stream axis, with
+        each stream's own delta as the multiplicand. One weight pass then
+        serves a whole ``[B, ...]`` tile of streams and bytes/stream falls
+        sublinearly with B (see
+        :func:`repro.core.perf_model.estimate_batched_tile`); without it
+        each stream pays its own fetch.
     """
 
     name: str
@@ -89,7 +92,7 @@ class BackendSpec:
     m_init: str = "bias"
     weight_bits: int = 32
     supports_custom_acts: bool = True
-    weight_fetch: str = "stream"
+    union_compaction: bool = False
 
 
 def register_backend(spec: BackendSpec) -> BackendSpec:
@@ -117,71 +120,22 @@ def _ensure_builtins() -> None:
     import repro.core.deltamamba2h  # noqa: F401  (registers mamba2h backends)
 
 
-def require_stream_tile(x, name: str) -> None:
-    """Tile-contract guard for the ``*_batch`` backends.
-
-    The batched kernels price ONE weight fetch per stream tile, so their
-    inputs must carry an explicit leading stream axis (``[B, ..., I]``).
-    Accepting a bare ``[I]`` vector would silently bill single-stream
-    traffic at tile rates; callers with one stream should use the
-    per-stream parent backend (or pass ``[1, I]`` to mean a 1-tile).
-    """
-    if getattr(x, "ndim", 0) < 2:
-        raise ValueError(
-            f"{name} computes a [B, ...] tile of streams per step (one "
-            f"weight pass serves the whole tile); got a {getattr(x, 'ndim', 0)}-D "
-            f"input — add a leading stream axis, or use the per-stream "
-            f"{name.removesuffix('_batch')!r} backend")
-
-
-# (cell, name) -> replacement: backends that USED to ship and were
-# deliberately retired. Looking one up names its successor instead of the
-# generic unknown-name rejection, so stale configs fail loudly and
-# actionably.
-REMOVED_BACKENDS = {
-    ("gru", "blocksparse"): "fused",
-}
-
-
 def get_backend(name: str, cell: str = "gru") -> BackendSpec:
-    """Look up a registered spec; unknown names raise with the known set.
-
-    Retired backends (``REMOVED_BACKENDS``) raise naming their
-    replacement — ``blocksparse`` was deregistered after benching ~45x
-    slower than ``fused`` (two separately-compacted delta_spmv calls per
-    step vs one fused pallas_call); its kernel survives in
-    :mod:`repro.kernels.delta_spmv` as an ablation, but it is no longer a
-    servable path.
-    """
+    """Look up a registered spec; unknown names raise with the known set."""
     _ensure_builtins()
     spec = _REGISTRY.get((cell, name))
     if spec is None:
-        repl = REMOVED_BACKENDS.get((cell, name))
-        if repl is not None:
-            raise ValueError(
-                f"{cell} backend {name!r} was removed; use {repl!r} "
-                f"instead (same math, one fused pallas_call per layer "
-                f"step instead of two separately-compacted spmv calls)")
-        known = backend_names(cell)
+        known = list_backends(cell)
         raise ValueError(
             f"unknown {cell} backend {name!r}; registered backends: {known}")
     return spec
 
 
 def list_backends(cell: str = "gru") -> tuple:
-    """Registered backend names for a cell, in registration order.
-
-    This is the query every "which backends exist" list must derive from —
-    the legacy ``repro.core.deltagru.BACKENDS`` tuple and the kernel-bench
-    backend sweeps all read it, so a newly registered backend is
-    automatically benched and regression-gated instead of silently skipped.
-    """
+    """Registered backend names for a cell, in registration order: the
+    one query every "which backends exist" list derives from."""
     _ensure_builtins()
     return tuple(n for (c, n) in _REGISTRY if c == cell)
-
-
-# Historical spelling of the same query.
-backend_names = list_backends
 
 
 def registered_backends(cell: str = "gru") -> tuple:

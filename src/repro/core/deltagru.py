@@ -41,15 +41,16 @@ is a registered :class:`repro.core.backends.BackendSpec`):
   applied at the activation stage, not folded into ``M``), and the Q8.8
   -> Q1.4 LUT sigmoid/tanh grid in-kernel. Quantize a trained stack with
   :func:`repro.quant.export.quantize_stack` and pass its layouts.
-* ``"fused_batch"`` / ``"fused_q8_batch"`` — the batched multi-stream
-  tile contracts over the same kernels: one weight pass serves a
-  ``[B, ...]`` tile of streams per layer step, compacting fired blocks on
-  the **union** of fired columns across the tile. A stream whose delta
-  slice in a union-fired block is all-zero contributes exactly ±0.0
-  partial products, so the batched paths are bit-identical (fp32) /
-  code-exact (q8) to their per-stream parents at every θ — only the
-  DRAM pricing changes (``weight_fetch="tile"``: one fetch per tile
-  instead of one per stream). They reject streamless ``[I]`` inputs.
+* ``"fused_q4"`` — the int4 twin of ``fused_q8``: nibble-packed weights
+  (half the q8 bytes per fired column), unpacked in-register.
+
+Every fused backend takes a ``[B, ...]`` tile of streams as it takes one
+stream: one weight pass per layer step, compacting fired blocks on the
+**union** of fired columns across the tile
+(``BackendSpec.union_compaction``). A stream whose delta slice in a
+union-fired block is all-zero contributes exactly ±0.0 partial products,
+so a stream's outputs do not depend on its companions at a fixed tile
+width, bitwise (fp32) and code-exact (q8/q4).
 
 ``dense`` and the fused fp32 paths are numerically equivalent to the
 Eq. 3 recurrence (the equivalence suite pins fused == dense == the Eq. 1
@@ -57,12 +58,6 @@ oracle at ``theta == 0``). ``fused_q8`` instead bit-matches the
 fake-quant fixed-point reference on the declared Qm.n grids
 (``tests/test_quant_backends.py``) and reduces to a quantized plain GRU
 at ``theta == 0``.
-
-(The historical ``"blocksparse"`` path — two separately-compacted
-:func:`repro.kernels.ops.delta_spmv` calls per step — was retired after
-benching ~45x slower than ``fused``; looking it up names ``fused`` as
-the replacement. The spmv kernel itself survives in
-:mod:`repro.kernels.delta_spmv` as an ablation.)
 """
 from __future__ import annotations
 
@@ -71,8 +66,7 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.backends import (BackendSpec, get_backend, list_backends,
-                                 register_backend, require_stream_tile)
+from repro.core.backends import BackendSpec, get_backend, register_backend
 from repro.core.delta import DeltaState, delta_encode, init_delta_state
 from repro.core.thresholds import layer_theta
 
@@ -363,46 +357,6 @@ def _step_fused_q4(params, state, x, theta_x, theta_h, *, sigmoid, tanh,
                                 layout=layout, interpret=interpret)
 
 
-def _step_fused_batch(params, state, x, theta_x, theta_h, *, sigmoid, tanh,
-                      matvec, layout, packed, interpret):
-    """Batched multi-stream tile contract over the fused fp32 kernel.
-
-    The fused kernel already compacts fired blocks on the **union** of
-    fired columns across its flattened leading axis
-    (:func:`repro.kernels.delta_q8._prep_step_operands` /
-    the fp32 twin in :mod:`repro.kernels.deltagru_seq`), with each
-    stream's own delta vector as the multiplicand — a stream that did not
-    fire a union-fired block contributes exact ±0.0 partial products, so
-    the tile result is bit-identical to running the streams one at a
-    time. This wrapper's job is the CONTRACT: require the stream axis, so
-    the ``weight_fetch="tile"`` pricing (one weight pass per tile) is
-    only ever attached to genuinely batched execution.
-    """
-    require_stream_tile(x, "fused_batch")
-    return _step_fused(params, state, x, theta_x, theta_h, sigmoid=sigmoid,
-                       tanh=tanh, matvec=matvec, layout=layout,
-                       packed=packed, interpret=interpret)
-
-
-def _step_fused_q8_batch(params, state, x, theta_x, theta_h, *, sigmoid,
-                         tanh, matvec, layout, packed, interpret):
-    """Batched tile contract over the int8 kernel (code-exact: the integer
-    accumulator adds exact zero codes for non-fired streams)."""
-    require_stream_tile(x, "fused_q8_batch")
-    return _step_fused_q8(params, state, x, theta_x, theta_h,
-                          sigmoid=sigmoid, tanh=tanh, matvec=matvec,
-                          layout=layout, packed=packed, interpret=interpret)
-
-
-def _step_fused_q4_batch(params, state, x, theta_x, theta_h, *, sigmoid,
-                         tanh, matvec, layout, packed, interpret):
-    """Batched tile contract over the int4 kernel (code-exact, like q8)."""
-    require_stream_tile(x, "fused_q4_batch")
-    return _step_fused_q4(params, state, x, theta_x, theta_h,
-                          sigmoid=sigmoid, tanh=tanh, matvec=matvec,
-                          layout=layout, packed=packed, interpret=interpret)
-
-
 # -- per-backend stack packers (registered BackendSpec.pack fns) ------------
 
 def _pack_none(params, block):
@@ -436,33 +390,16 @@ register_backend(BackendSpec(
     m_init="bias", weight_bits=32, supports_custom_acts=True))
 register_backend(BackendSpec(
     name="fused", cell="gru", pack=_pack_fused, step=_step_fused,
-    m_init="bias", weight_bits=32, supports_custom_acts=False))
+    m_init="bias", weight_bits=32, supports_custom_acts=False,
+    union_compaction=True))
 register_backend(BackendSpec(
     name="fused_q8", cell="gru", pack=_pack_fused_q8, step=_step_fused_q8,
-    m_init="zero", weight_bits=8, supports_custom_acts=False))
-# Batched multi-stream tiles: same pack fns (and therefore the same
-# packed layouts / m_init conventions) as their per-stream parents, so
-# DeltaProgram.with_backend can swap between the pair without repacking.
-register_backend(BackendSpec(
-    name="fused_batch", cell="gru", pack=_pack_fused,
-    step=_step_fused_batch, m_init="bias", weight_bits=32,
-    supports_custom_acts=False, weight_fetch="tile"))
-register_backend(BackendSpec(
-    name="fused_q8_batch", cell="gru", pack=_pack_fused_q8,
-    step=_step_fused_q8_batch, m_init="zero", weight_bits=8,
-    supports_custom_acts=False, weight_fetch="tile"))
+    m_init="zero", weight_bits=8, supports_custom_acts=False,
+    union_compaction=True))
 register_backend(BackendSpec(
     name="fused_q4", cell="gru", pack=_pack_fused_q4, step=_step_fused_q4,
-    m_init="zero", weight_bits=4, supports_custom_acts=False))
-register_backend(BackendSpec(
-    name="fused_q4_batch", cell="gru", pack=_pack_fused_q4,
-    step=_step_fused_q4_batch, m_init="zero", weight_bits=4,
-    supports_custom_acts=False, weight_fetch="tile"))
-
-# Legacy alias, now DERIVED from the registry instead of hand-maintained:
-# a backend registered after import still shows up via list_backends("gru");
-# this tuple is only the snapshot of the builtins above.
-BACKENDS = list_backends("gru")
+    m_init="zero", weight_bits=4, supports_custom_acts=False,
+    union_compaction=True))
 
 
 def deltagru_step(params: GruLayerParams, state: DeltaGruLayerState, x: Array,
@@ -482,9 +419,8 @@ def deltagru_step(params: GruLayerParams, state: DeltaGruLayerState, x: Array,
         precedence over ``backend`` (rejected by ``fused_q8``, whose state
         lives in the code domain).
       backend: any registered GRU backend name (builtin:
-        ``"dense" | "fused" | "fused_q8" | "fused_batch" |
-        "fused_q8_batch"``, see module docstring). Unknown names raise;
-        retired names raise naming their replacement.
+        ``"dense" | "fused" | "fused_q8" | "fused_q4"``, see module
+        docstring). Unknown names raise, listing the registered set.
       layout: optional pre-packed :class:`FusedGruLayout` (fused) or
         :class:`QuantGruLayout` (fused_q8) for the kernel backends
         (packed/quantized on the fly otherwise — sequence entry points
@@ -499,9 +435,8 @@ def deltagru_step(params: GruLayerParams, state: DeltaGruLayerState, x: Array,
     points handle this automatically when they build the initial state,
     and the :class:`~repro.core.program.DeltaGruProgram` API makes the
     mismatch unrepresentable.
-      packed: legacy kwarg (pre-padded spmv operand pairs); unused by the
-        builtin backends since ``blocksparse`` was retired, kept for
-        registered third-party specs.
+      packed: pre-padded spmv operand pairs; unused by the builtin
+        backends, passed through to registered third-party specs.
       interpret: Pallas mode for the kernel backends. ``None`` (default)
         auto-selects: compiled kernels on TPU, the pure-jnp references
         elsewhere. ``True`` forces interpret-mode emulation — the

@@ -29,11 +29,11 @@ Execution backends go through the same registry as DeltaGRU
   stack with :func:`repro.quant.export.quantize_delta_stack`
   (``cell="lstm"``) or just compile:
   ``compile_delta_program(params, cell="lstm", backend="fused_q8")``.
-* ``"fused_batch"`` / ``"fused_q8_batch"`` — batched multi-stream tile
-  contracts over the same kernels (one weight pass per ``[B, ...]``
-  stream tile, compacted on the union of fired columns across the tile;
-  ``weight_fetch="tile"``); bit-identical (fp32) / code-exact (q8) to
-  their per-stream parents, streamless ``[I]`` inputs rejected.
+* ``"fused_q4"`` — the int4 twin of ``fused_q8`` (nibble-packed weights).
+
+As for DeltaGRU, every fused backend takes a ``[B, ...]`` tile of streams
+as it takes one: one weight pass per layer step, compacted on the union
+of fired columns across the tile (``BackendSpec.union_compaction``).
 
 Both compile into :func:`repro.core.program.compile_delta_program`
 programs (``cell="lstm"``) and stream through
@@ -47,8 +47,7 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.backends import (BackendSpec, get_backend, register_backend,
-                                 require_stream_tile)
+from repro.core.backends import BackendSpec, get_backend, register_backend
 from repro.core.delta import DeltaState, delta_encode, init_delta_state
 from repro.core.thresholds import layer_theta
 
@@ -285,44 +284,6 @@ def _step_fused_q4(params, state, x, theta_x, theta_h, *, sigmoid, tanh,
                           layout=layout, packed=packed, interpret=interpret)
 
 
-def _step_fused_batch(params, state, x, theta_x, theta_h, *, sigmoid, tanh,
-                      matvec, layout=None, packed=None, interpret=None):
-    """Batched multi-stream tile contract over the fused fp32 LSTM kernel.
-
-    The kernel compacts fired blocks on the **union** of fired columns
-    across its flattened leading (stream) axis; a stream whose delta
-    slice in a union-fired block is all-zero contributes exact ±0.0
-    partial products, so the tile result is bit-identical to per-stream
-    execution. The wrapper enforces the contract that makes the
-    ``weight_fetch="tile"`` pricing honest: a leading stream axis.
-    """
-    require_stream_tile(x, "fused_batch")
-    return _step_fused(params, state, x, theta_x, theta_h, sigmoid=sigmoid,
-                       tanh=tanh, matvec=matvec, layout=layout,
-                       packed=packed, interpret=interpret)
-
-
-def _step_fused_q8_batch(params, state, x, theta_x, theta_h, *, sigmoid,
-                         tanh, matvec, layout=None, packed=None,
-                         interpret=None):
-    """Batched tile contract over the int8 LSTM kernel (code-exact: the
-    integer accumulator adds exact zero codes for non-fired streams)."""
-    require_stream_tile(x, "fused_q8_batch")
-    return _step_fused_q8(params, state, x, theta_x, theta_h,
-                          sigmoid=sigmoid, tanh=tanh, matvec=matvec,
-                          layout=layout, packed=packed, interpret=interpret)
-
-
-def _step_fused_q4_batch(params, state, x, theta_x, theta_h, *, sigmoid,
-                         tanh, matvec, layout=None, packed=None,
-                         interpret=None):
-    """Batched tile contract over the int4 LSTM kernel (code-exact)."""
-    require_stream_tile(x, "fused_q4_batch")
-    return _step_fused_q4(params, state, x, theta_x, theta_h,
-                          sigmoid=sigmoid, tanh=tanh, matvec=matvec,
-                          layout=layout, packed=packed, interpret=interpret)
-
-
 # -- per-backend stack packers (registered BackendSpec.pack fns) ------------
 
 def _pack_none(params, block):
@@ -357,27 +318,16 @@ register_backend(BackendSpec(
     m_init="bias", weight_bits=32, supports_custom_acts=True))
 register_backend(BackendSpec(
     name="fused", cell="lstm", pack=_pack_fused, step=_step_fused,
-    m_init="bias", weight_bits=32, supports_custom_acts=False))
+    m_init="bias", weight_bits=32, supports_custom_acts=False,
+    union_compaction=True))
 register_backend(BackendSpec(
     name="fused_q8", cell="lstm", pack=_pack_fused_q8, step=_step_fused_q8,
-    m_init="zero", weight_bits=8, supports_custom_acts=False))
-# Batched multi-stream tiles: same pack fns / m_init as the per-stream
-# parents so DeltaProgram.with_backend swaps between the pair in place.
-register_backend(BackendSpec(
-    name="fused_batch", cell="lstm", pack=_pack_fused,
-    step=_step_fused_batch, m_init="bias", weight_bits=32,
-    supports_custom_acts=False, weight_fetch="tile"))
-register_backend(BackendSpec(
-    name="fused_q8_batch", cell="lstm", pack=_pack_fused_q8,
-    step=_step_fused_q8_batch, m_init="zero", weight_bits=8,
-    supports_custom_acts=False, weight_fetch="tile"))
+    m_init="zero", weight_bits=8, supports_custom_acts=False,
+    union_compaction=True))
 register_backend(BackendSpec(
     name="fused_q4", cell="lstm", pack=_pack_fused_q4, step=_step_fused_q4,
-    m_init="zero", weight_bits=4, supports_custom_acts=False))
-register_backend(BackendSpec(
-    name="fused_q4_batch", cell="lstm", pack=_pack_fused_q4,
-    step=_step_fused_q4_batch, m_init="zero", weight_bits=4,
-    supports_custom_acts=False, weight_fetch="tile"))
+    m_init="zero", weight_bits=4, supports_custom_acts=False,
+    union_compaction=True))
 
 
 def lstm_stack_m_init(backend: str) -> str:
@@ -394,8 +344,8 @@ def deltalstm_step(params: LstmLayerParams, state: DeltaLstmLayerState,
                    layout=None, packed=None,
                    interpret: bool | None = None) -> DeltaLstmStepOut:
     """One DeltaLSTM timestep, dispatched through the ``cell="lstm"``
-    registry (builtin: ``"dense" | "fused" | "fused_q8" | "fused_batch" |
-    "fused_q8_batch"``). ``layout`` / ``packed`` / ``interpret`` follow
+    registry (builtin: ``"dense" | "fused" | "fused_q8" | "fused_q4"``).
+    ``layout`` / ``packed`` / ``interpret`` follow
     the GRU-style step contract."""
     spec = get_backend(backend, cell="lstm")
     return spec.step(params, state, x, theta_x, theta_h, sigmoid=sigmoid,

@@ -10,10 +10,9 @@ This module reproduces, exactly, the paper's estimation machinery:
 * Eq. 8  — memory-bounded peak throughput and sparsity-normalized batch-1
            throughput (validated against Table VI).
 
-It also carries the TPU-v5e translation used by the roofline harness: for a
-batch-1 (or small-batch decode) DeltaGRU/delta-linear workload the dominant
-term is weight traffic, and temporal sparsity divides that term by
-``1/(1-Gamma_eff)`` — the same law, different constants.
+It also prices a batched stream tile (one weight pass at the union firing
+of its streams). The chip's own peaks, which the benchmark's roofline
+metrics read, are in ``bench/peaks.json``.
 """
 from __future__ import annotations
 
@@ -189,23 +188,6 @@ def union_sparsity(gamma, batch: int):
     return gamma ** batch
 
 
-def tile_dram_traffic_bytes_per_timestep(dims: GruDims, gamma_dx_union,
-                                         gamma_dh_union,
-                                         w_weight_bits: int = 8):
-    """Eq. 7 bytes term for a batched tile: weight bytes fetched ONCE per
-    ``[B, ...]`` stream tile per timestep.
-
-    The batched kernels (``weight_fetch="tile"``) compact fired blocks on
-    the union of fired columns across the tile, so the fetch volume is
-    the ordinary :func:`dram_traffic_bytes_per_timestep` evaluated at the
-    **union** gammas — and per-stream traffic is this divided by B.
-    Traced-safe (the serving engine accumulates it on-device from
-    measured union firing fractions).
-    """
-    return dram_traffic_bytes_per_timestep(dims, gamma_dx_union,
-                                           gamma_dh_union, w_weight_bits)
-
-
 def estimate_batched_tile(dims: GruDims, gamma_dx: float, gamma_dh: float,
                           batch: int,
                           spec: AcceleratorSpec = EDGEDRNN) -> dict:
@@ -217,7 +199,9 @@ def estimate_batched_tile(dims: GruDims, gamma_dx: float, gamma_dh: float,
 
     * tile latency  = Eq. 7 latency at the union gammas (the weight
       stream is the bottleneck and is shared),
-    * tile bytes    = Eq. 7 traffic at the union gammas,
+    * tile bytes    = Eq. 7 traffic at the union gammas: the fused
+      kernels (``BackendSpec.union_compaction``) fetch the union of fired
+      columns across the tile once,
     * bytes/stream  = tile bytes / B  (sublinear in B: the numerator
       grows with the union firing),
     * throughput    = B steps retired per tile pass.
@@ -225,7 +209,7 @@ def estimate_batched_tile(dims: GruDims, gamma_dx: float, gamma_dh: float,
     gx_u = union_sparsity(gamma_dx, batch)
     gh_u = union_sparsity(gamma_dh, batch)
     lat = stack_latency_s(dims, gx_u, gh_u, spec)
-    tile_bytes = tile_dram_traffic_bytes_per_timestep(
+    tile_bytes = dram_traffic_bytes_per_timestep(
         dims, gx_u, gh_u, w_weight_bits=spec.w_weight_bits)
     ops = dims.params_per_timestep_ops * batch
     return {
@@ -237,59 +221,3 @@ def estimate_batched_tile(dims: GruDims, gamma_dx: float, gamma_dh: float,
         "weight_bytes_per_stream": tile_bytes / batch,
         "throughput_ops": ops / lat if lat > 0 else float("inf"),
     }
-
-
-# ---------------------------------------------------------------------------
-# TPU v5e translation: same law, different constants.
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class TpuChipSpec:
-    peak_bf16_flops: float = 197e12   # per chip
-    hbm_bw: float = 819e9             # bytes/s per chip
-    ici_bw: float = 50e9              # bytes/s per link
-
-
-V5E = TpuChipSpec()
-
-
-def tpu_batch1_gru_roofline(dims: GruDims, gamma_eff: float,
-                            batch: int = 1, weight_bytes: int = 2,
-                            chip: TpuChipSpec = V5E) -> dict:
-    """Roofline terms for a delta-GRU decode step on one v5e chip.
-
-    compute term  = batch * Op / peak_flops
-    memory term   = surviving weight bytes / hbm_bw   (weights dominate at
-                    batch ~ 1; activations are KB-scale and ignored, as in
-                    the paper's analysis)
-    """
-    ops = dims.params_per_timestep_ops * batch
-    wbytes = dims.n_params * weight_bytes * (1.0 - gamma_eff)
-    t_compute = ops / chip.peak_bf16_flops
-    t_memory = wbytes / chip.hbm_bw
-    return {
-        "t_compute": t_compute,
-        "t_memory": t_memory,
-        "bound": "memory" if t_memory >= t_compute else "compute",
-        "effective_ops_per_s": ops / max(t_compute, t_memory),
-        "speedup_vs_dense": 1.0 / (1.0 - gamma_eff),
-    }
-
-
-def batch_sweep(dims: GruDims, batches, weight_bytes: int = 2,
-                act_bytes: int = 2, chip: TpuChipSpec = V5E,
-                gamma_eff: float = 0.0) -> list[dict]:
-    """Fig. 13 analogue: throughput & latency vs batch size.
-
-    Weights are fetched once per step regardless of batch (reuse), so
-    throughput rises toward the compute roofline with batch while latency
-    grows once compute dominates.
-    """
-    rows = []
-    for b in batches:
-        ops = dims.params_per_timestep_ops * b
-        wbytes = dims.n_params * weight_bytes * (1.0 - gamma_eff)
-        abytes = act_bytes * b * (dims.input_size + 2 * dims.hidden_size * dims.num_layers)
-        t = max(ops / chip.peak_bf16_flops, (wbytes + abytes) / chip.hbm_bw)
-        rows.append({"batch": b, "latency_s": t, "throughput_ops": ops / t})
-    return rows

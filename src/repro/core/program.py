@@ -302,32 +302,6 @@ class DeltaProgram:
         """Same program, different Pallas mode (kernel-correctness runs)."""
         return replace(self, interpret=interpret)
 
-    def with_backend(self, backend: str) -> "DeltaProgram":
-        """Same packed weights, different (pack-compatible) backend.
-
-        Only backends that share THIS program's ``pack`` function and
-        ``m_init`` convention are accepted — i.e. the layouts compiled
-        here are byte-for-byte what the new backend's kernels expect and
-        the state convention is unchanged (states remain name-tagged:
-        mint fresh ones via ``init_state``). That is exactly the
-        per-stream <-> batched pairs (``fused`` <-> ``fused_batch``, ``fused_q8`` <->
-        ``fused_q8_batch``), which register with the same pack fn; the
-        serving engine uses this to route multi-stream programs onto the
-        tile-fetch variants without repacking. Anything else must go
-        through :func:`compile_delta_program` again.
-        """
-        if backend == self.backend:
-            return self
-        new = get_backend(backend, cell=self.cell)
-        cur = self.spec
-        if new.pack is not cur.pack or new.m_init != cur.m_init:
-            raise ValueError(
-                f"backend {backend!r} packs weights differently from "
-                f"{self.backend!r} (pack/m_init mismatch); the compiled "
-                "layouts cannot be reused — recompile with "
-                "compile_delta_program(params, backend=...)")
-        return replace(self, backend=backend)
-
 
 jax.tree_util.register_pytree_node(
     DeltaProgram,

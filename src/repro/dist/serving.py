@@ -66,9 +66,9 @@ class ShardedStreamFleet:
     Args:
       program: a compiled :class:`~repro.core.program.DeltaProgram` with a
         classifier head (``fused`` / ``fused_q8`` of either cell; with a
-        per-shard width > 1 the template engine auto-routes onto the
-        ``*_batch`` tile sibling, so one weight pass per tick serves each
-        shard's whole tile).
+        per-shard width > 1 each shard's kernel compacts on the union of
+        its tile, so one weight pass per tick serves the shard's whole
+        tile).
       task: the :class:`~repro.models.gru_rnn.GruTaskConfig`.
       n_streams: fleet-wide slot count; must divide evenly over the data
         axis (each shard runs a fixed-width tile — the bitwise parity and

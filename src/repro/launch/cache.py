@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside or at one path.
 
-Every process that drives a chip (``chip_smoke.py``, the benchmark entry
-points) calls :func:`enable_compile_cache` before it compiles anything.
+``chip_smoke.py`` calls :func:`enable_compile_cache` before it compiles
+anything.
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it by itself and
 nothing is set here. Otherwise the cache lives at ``<checkout>/.jax_cache``:
 a fixed path, so a later process in the same checkout finds what an

@@ -16,7 +16,7 @@ and records:
 Usage::
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-32b \
-        --shape train_4k [--multi-pod] [--out benchmarks/artifacts/dryrun]
+        --shape train_4k [--multi-pod] [--out .bench_work/dryrun]
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 
 The XLA_FLAGS line above MUST stay the first statement: jax freezes the
@@ -45,7 +45,7 @@ from repro.train.trainer import make_lm_train_step_fn
 # dry-run lowers the jnp reference path (identical math; see kernels.ops).
 kops.set_force_ref(True)
 
-DEFAULT_OUT = "benchmarks/artifacts/dryrun"
+DEFAULT_OUT = ".bench_work/dryrun"
 
 # ---------------------------------------------------------------------------
 # Collective-bytes accounting (conventions: DESIGN.md §10)

@@ -84,7 +84,7 @@ def gru_model_forward(params, cfg: GruTaskConfig, xs: Array, *,
     backend run the delta path, and its head (or ``params``'s, when the
     program was compiled from a bare stack) produces the outputs. The
     legacy ``backend=`` / ``layouts=`` kwargs remain for ad-hoc /
-    training-time calls (``dense | fused | fused_q8 | fused_batch | fused_q8_batch``, see
+    training-time calls (``dense | fused | fused_q8 | fused_q4``, see
     :mod:`repro.core.deltagru`); the fused kernels hard-code the Fig. 7
     activation pipeline, so QAT activation policies require ``dense``.
 

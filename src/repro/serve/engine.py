@@ -23,16 +23,17 @@ The engine supports the dual thresholds (including per-layer
 :class:`~repro.core.thresholds.ThresholdPolicy` overrides, threaded into
 the jitted step), the dynamic-threshold controller (paper Sec. VI future
 work), every backend registered for the program's cell
-(both cells: ``dense | fused | fused_q8 | fused_batch | fused_q8_batch``
-— the ``fused_q8*`` paths stream int8 packed weights and run the paper's
-fixed-point pipeline via the cell-agnostic :mod:`repro.kernels.delta_q8`
-core), chunked ``step_many`` streaming, and a batched multi-stream mode:
-with ``n_streams > 1`` the engine **auto-routes** a ``fused`` /
-``fused_q8`` program onto its ``*_batch`` tile sibling
-(:meth:`~repro.core.program.DeltaProgram.with_backend` — same packed
-weights, bit-identical outputs) so ONE weight fetch per step serves the
-whole stream tile, and the Eq. 7 accounting gains tile-level terms
-priced on the **union** firing across streams. On top of the slots sits a
+(both cells: ``dense | fused | fused_q8 | fused_q4`` — the quantized
+paths stream int8/int4 packed weights and run the paper's fixed-point
+pipeline via the cell-agnostic :mod:`repro.kernels.delta_q8` core),
+chunked ``step_many`` streaming, and a batched multi-stream mode: with
+``n_streams > 1`` the program's kernel runs the ``[N, ...]`` tile of
+streams in one call, and where it compacts on the union of fired columns
+across the tile (``BackendSpec.union_compaction``: the fused backends)
+ONE weight fetch per step serves the whole tile, so the Eq. 7 accounting
+gains tile-level terms priced on the **union** firing across streams.
+The program is never swapped: the tile is a property of the call. On
+top of the slots sits a
 **session API** for heavy traffic:
 :meth:`~DeltaStreamEngine.open_stream` claims a free slot and
 masked-resets only that stream's state,
@@ -237,8 +238,8 @@ class StreamStats:
 
     The ``ufired_*`` / ``tile_*`` fields are the batched-tile terms:
     union firing across the stream tile and the Eq. 7 latency/bytes of
-    the ONE weight pass that serves it (meaningful on
-    ``weight_fetch="tile"`` backends; for a single stream they equal the
+    the ONE weight pass that serves it (reported for a tile of streams on a
+    union-compacting kernel; for a single stream they equal the
     per-stream terms).
     """
 
@@ -316,12 +317,11 @@ class DeltaStreamEngine:
         kernel (the heavy-traffic mode: weights are fetched once per step
         for all slots). ``step``/``step_many`` then take ``[N, I]`` /
         ``[T, N, I]``. Slots double as serving sessions via
-        :meth:`open_stream` / :meth:`close_stream`. When a pack-compatible
-        ``*_batch`` tile backend is registered for the program's backend
-        (``fused`` / ``fused_q8`` both cells), ``n_streams > 1`` routes
-        the program onto it — outputs are bit-identical, and
-        :meth:`report` additionally prices the tile economics: one weight
-        fetch per step at the UNION firing across streams, with
+        :meth:`open_stream` / :meth:`close_stream`. When the program's
+        kernel compacts on the union across the tile (the fused
+        backends), ``n_streams > 1`` makes :meth:`report` price the tile
+        economics as well: one weight fetch per step at the UNION firing
+        across streams, with
         ``weight_bytes_per_stream_per_step = tile bytes / n_streams``.
         The per-stream session accounting keeps its historical meaning
         (what each stream would cost served alone on a batch-1 device).
@@ -365,18 +365,10 @@ class DeltaStreamEngine:
                 "DeltaStreamEngine needs a program with a classifier head; "
                 "compile from an init_gru_model / init_lstm_model params "
                 "dict")
-        # Multi-stream routing: a tile of streams should pay ONE weight
-        # fetch per step, so swap onto the pack-compatible "*_batch"
-        # sibling when one is registered (same packed layouts, same math
-        # — outputs stay bit-identical). Backends with no batched sibling
-        # (e.g. "dense") keep per-stream pricing.
-        if (n_streams > 1
-                and program.spec.weight_fetch != "tile"):
-            try:
-                program = program.with_backend(program.backend + "_batch")
-            except ValueError:
-                pass
-        self._tile_fetch = program.spec.weight_fetch == "tile"
+        # a tile of streams pays ONE weight fetch per step where the
+        # kernel compacts on the union across its stream axis; other
+        # backends (e.g. "dense") keep per-stream pricing
+        self._tile_fetch = n_streams > 1 and program.spec.union_compaction
         self.program = program
         self.params = list(program.layers)   # legacy attr (the cell stack)
         self.head = (program.head, program.head_b)
@@ -499,7 +491,7 @@ class DeltaStreamEngine:
                 "agg_w_bytes": carry["agg_w_bytes"] + jnp.mean(wb),
                 # tile-level lifetime aggregates (scalars): union firing
                 # + the once-per-tile weight pass it prices; reported
-                # only on tile-fetch backends but carried uniformly
+                # only for a union-compacted tile but carried uniformly
                 "agg_ufired_x": carry["agg_ufired_x"] + ufx,
                 "agg_ufired_h": carry["agg_ufired_h"] + ufh,
                 "agg_tile_lat_s": carry["agg_tile_lat_s"] + tile_lat,

@@ -2,9 +2,8 @@
 
 Everything here is SEEDED — a :class:`FaultPlan` maps ``(seed, stream
 index, tick)`` to faults with no ambient randomness, so a chaos soak run
-twice produces bit-identical fault schedules and the regression gate can
-assert EXACT recovery counts (``benchmarks/soak_serving.py`` →
-``BENCH_soak.json``).
+twice produces bit-identical fault schedules and a test can assert EXACT
+recovery counts (``tests/test_resilience.py::TestChaosSoak``).
 
 Fault classes (each maps to a real edge-deployment failure the paper's
 target environment — a sensor-fed PS/PL SoC — actually sees):

@@ -29,7 +29,7 @@ death. Division of labor:
 Every policy trigger (admission, deadlines, quarantine, overload) is
 counted in TICKS, never wall time, so a seeded chaos run reproduces its
 shed/quarantine/recovery counts exactly — that is what lets
-``benchmarks/soak_serving.py`` gate them as hard numbers in CI. The only
+``tests/test_resilience.py::TestChaosSoak`` assert them run to run. The only
 wall-clock consumers are the heartbeat/straggler instruments, whose flags
 are reported but never part of exact gates.
 """

@@ -36,8 +36,7 @@ load-generator gates).
 The router is deliberately wall-clock-free in its decisions: placement,
 admission, shedding, and rebalance all count ticks, so a seeded load run
 reproduces its entire event history exactly on any machine. Wall time is
-only *measured*, by whoever drives the ticks
-(:func:`repro.serve.loadgen.run_fabric_load` times each one).
+only *measured*, by whoever drives the ticks.
 """
 from __future__ import annotations
 

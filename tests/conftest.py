@@ -3,8 +3,7 @@
 The dist/ft tests need a handful of local devices; 8 is the conventional
 unit-test topology. This is deliberately NOT the dry-run's 512 (that env is
 confined to launch/dryrun.py, which must never be imported from tests), and
-benchmarks/run.py is a separate process that still sees the real device
-count.
+bench/run.py is a separate process that still sees the real device count.
 """
 import os
 import sys

@@ -1,7 +1,7 @@
 """Cross-backend DeltaGRU equivalence + zero-sync engine regression.
 
-The execution paths (dense XLA, fused single-kernel sequence path, and
-the batched ``fused_batch`` stream-tile variant) must agree with each
+The execution paths (dense XLA and the fused single-kernel sequence path,
+which takes one stream or a ``[B, ...]`` tile alike) must agree with each
 other and — at ``theta == 0`` — with the plain-GRU Eq. 1 oracle. The
 streaming engine's on-device gamma/latency accounting must reproduce the
 seed's host-side accounting exactly.
@@ -22,11 +22,8 @@ from repro.serve.engine import GruStreamEngine
 
 # (backend, extra kwargs): "fused" auto-routes to the jnp ref off-TPU, so
 # the interpret=True rows are what actually exercise the Pallas kernel here.
-# fused_batch is the same kernel behind the stream-tile contract; all the
-# sequences here carry a [T, B, I] batch axis, so it is a drop-in row.
-KERNEL_PATHS = [("fused", {}), ("fused", {"interpret": True}),
-                ("fused_batch", {}), ("fused_batch", {"interpret": True})]
-KERNEL_BACKENDS = ("fused", "fused_batch")
+KERNEL_PATHS = [("fused", {}), ("fused", {"interpret": True})]
+KERNEL_BACKENDS = ("fused",)
 
 
 def _stack_and_xs(key, i, h, layers, t, b, dtype=jnp.float32, scale=0.5):

@@ -1,49 +1,40 @@
-"""Batched stream-tile backends: one weight pass serves a [B, ...] tile.
+"""Stream tiles: one weight pass serves a [B, ...] tile of streams.
 
-The contract under test, per backend pair:
+A backend is registered once per kernel; whether a call runs one stream
+or a tile is the shape of its input. The contract under test:
 
-* ``fused_batch`` / ``fused_q8_batch`` are registered for BOTH cells with
-  ``weight_fetch="tile"`` and the same pack fn / ``m_init`` as their
-  per-stream siblings, so :meth:`DeltaProgram.with_backend` can swap a
-  compiled program onto the tile variant without repacking — and rejects
-  every pack-incompatible hop.
-* The batched step is the SAME math as the per-stream fused step on the
-  same tile — ``assert_array_equal``, jnp-ref and Pallas-interpret, GRU
-  and LSTM, theta = 0 and dual thresholds — it only adds the stream-tile
-  contract (a streamless ``[I]`` input is rejected with a pointer at the
-  per-stream spelling).
+* A tile is the same math as its streams run alone. q8 is code-exact to
+  each stream served at ``[T, 1, I]``. fp32 is bitwise to each stream
+  served in a tile of the same width (here: the stream replicated), and
+  within float tolerance at ``[T, 1, I]``: XLA's matmul row results move
+  ~1 ulp with the number of rows, which the q8 grid absorbs.
 * Union compaction must not leak between streams: at a FIXED tile width,
   swapping the companion streams (which changes the set of fired columns
   the tile fetches) leaves a stream's outputs bit-identical in fp32 and
-  code-exact in q8. (True bitwise batch-vs-solo equality in fp32 is not
-  a property XLA offers — matmul row results shift by ~1 ulp with the
-  number of rows — so cross-width fp32 parity is asserted at float
-  tolerance while the q8 grid absorbs the jitter and stays exact.)
-* The serving engine auto-routes multi-stream sessions onto the tile
-  variants, keeps per-stream served-alone accounting unchanged, and adds
-  tile-level union-firing economics to ``report()``.
-* ``blocksparse`` is gone: the registry names its replacement instead of
-  pretending the name never existed.
+  code-exact in q8.
+* The serving engine never swaps the program. Its ``report()`` prices a
+  tile (one weight pass at the union firing) where ``n_streams > 1`` and
+  the kernel compacts on the union (``BackendSpec.union_compaction``),
+  and keeps each stream's served-alone accounting unchanged.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backends import backend_names, get_backend
-from repro.core.deltagru import (deltagru_sequence, deltagru_step,
-                                 init_deltagru_state, init_gru_layer,
-                                 init_gru_stack)
+from repro.core.deltagru import deltagru_sequence, init_gru_stack
 from repro.core.deltalstm import deltalstm_sequence, init_lstm_stack
 from repro.core.perf_model import estimate_batched_tile, union_sparsity
-from repro.core.program import compile_delta_program, compile_deltagru
+from repro.core.program import compile_delta_program
 from repro.core.sparsity import GruDims
-from repro.models.gru_rnn import GruTaskConfig, init_gru_model
+from repro.models.gru_rnn import GruTaskConfig, init_gru_model, init_lstm_model
 from repro.quant.export import quantize_stack
-from repro.serve.engine import GruStreamEngine
+from repro.serve.engine import DeltaStreamEngine, GruStreamEngine
 from repro.serve.scheduler import GruStreamBatcher
 
 THETAS = [(0.0, 0.0), (0.05, 0.1)]
+CELLS = ("gru", "lstm")
+FUSED = ("fused", "fused_q8", "fused_q4")
 
 
 def _gru_stack_and_xs(key=0, i=14, h=32, layers=2, t=16, b=4, scale=0.5):
@@ -53,78 +44,29 @@ def _gru_stack_and_xs(key=0, i=14, h=32, layers=2, t=16, b=4, scale=0.5):
     return params, xs
 
 
-class TestRegistry:
-    @pytest.mark.parametrize("cell", ["gru", "lstm"])
-    def test_tile_backends_registered_for_both_cells(self, cell):
-        names = set(backend_names(cell))
-        assert {"fused_batch", "fused_q8_batch"} <= names
-
-    @pytest.mark.parametrize("cell", ["gru", "lstm"])
-    @pytest.mark.parametrize("base,batched", [("fused", "fused_batch"),
-                                              ("fused_q8", "fused_q8_batch")])
-    def test_tile_spec_mirrors_per_stream_sibling(self, cell, base, batched):
-        s, b = get_backend(base, cell=cell), get_backend(batched, cell=cell)
-        assert s.weight_fetch == "stream"
-        assert b.weight_fetch == "tile"
-        # the pack-compatibility with_backend relies on
-        assert b.pack is s.pack
-        assert b.m_init == s.m_init
-        assert b.weight_bits == s.weight_bits
-
-    def test_blocksparse_tombstone_names_replacement(self):
-        with pytest.raises(ValueError, match="removed; use 'fused'"):
-            get_backend("blocksparse")
-        assert "blocksparse" not in backend_names("gru")
-        # the tombstone is gru-keyed: lstm never had the backend
-        with pytest.raises(ValueError, match="unknown lstm backend"):
-            get_backend("blocksparse", cell="lstm")
+def _lstm_stack_and_xs(key=2):
+    params = init_lstm_stack(jax.random.PRNGKey(key), 12, 24, 2)
+    xs = jax.random.normal(jax.random.PRNGKey(key + 1), (14, 3, 12)) * 0.5
+    return params, xs
 
 
-class TestWithBackend:
-    @pytest.mark.parametrize("base,batched", [("fused", "fused_batch"),
-                                              ("fused_q8", "fused_q8_batch")])
-    def test_pack_compatible_swap_reuses_layouts(self, base, batched):
-        params, xs = _gru_stack_and_xs()
-        prog = compile_deltagru(params, backend=base)
-        swapped = prog.with_backend(batched)
-        assert swapped.backend == batched
-        assert swapped.layouts is prog.layouts          # no repack
-        got, _, _ = swapped.sequence(xs, 0.05, 0.1)
-        want, _, _ = prog.sequence(xs, 0.05, 0.1)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    def test_same_backend_is_identity(self):
-        params, _ = _gru_stack_and_xs()
-        prog = compile_deltagru(params, backend="fused")
-        assert prog.with_backend("fused") is prog
-
-    @pytest.mark.parametrize("base,bad", [("fused", "fused_q8_batch"),
-                                          ("dense", "fused_batch"),
-                                          ("fused_q8", "fused_batch")])
-    def test_pack_incompatible_swap_rejected(self, base, bad):
-        params, _ = _gru_stack_and_xs()
-        prog = compile_deltagru(params, backend=base)
-        with pytest.raises(ValueError, match="packs weights differently"):
-            prog.with_backend(bad)
-
-
-class TestStreamTileContract:
-    @pytest.mark.parametrize("batched", ["fused_batch", "fused_q8_batch"])
-    def test_streamless_input_rejected_with_pointer(self, batched):
-        p = init_gru_layer(jax.random.PRNGKey(0), 8, 16)
-        st = init_deltagru_state(p, ())
-        with pytest.raises(ValueError, match="leading stream axis"):
-            deltagru_step(p, st, jnp.ones((8,)), 0.0, 0.0, backend=batched)
-
-    def test_width_one_tile_accepted(self):
-        """B=1 is a legal tile — the engine routes on stream COUNT, the
-        kernel contract only demands the axis exist."""
-        params, xs = _gru_stack_and_xs(b=1)
-        got, _, _ = deltagru_sequence(params, xs, 0.05, 0.1,
-                                      backend="fused_batch")
-        want, _, _ = deltagru_sequence(params, xs, 0.05, 0.1,
-                                       backend="fused")
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+def _assert_fp32_tile_equals_solo(sequence, params, xs, tx, th, interpret):
+    """Each stream of a fused fp32 tile: bitwise to the stream replicated
+    across a tile of the same width, and within float tolerance of the
+    stream run alone at [T, 1, I]."""
+    got, _, _ = sequence(params, xs, tx, th, backend="fused",
+                         interpret=interpret)
+    b = xs.shape[1]
+    for s in range(b):
+        own = jnp.tile(xs[:, s:s + 1], (1, b, 1))
+        same_width, _, _ = sequence(params, own, tx, th, backend="fused",
+                                    interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(got)[:, s],
+                                      np.asarray(same_width)[:, 0])
+        alone, _, _ = sequence(params, xs[:, s:s + 1], tx, th,
+                               backend="fused", interpret=interpret)
+        np.testing.assert_allclose(np.asarray(got)[:, s],
+                                   np.asarray(alone)[:, 0], atol=1e-6)
 
 
 class TestTileParity:
@@ -132,47 +74,39 @@ class TestTileParity:
     @pytest.mark.parametrize("tx,th", THETAS)
     def test_gru_fp32_bit_identical_to_fused_on_same_tile(self, interpret,
                                                           tx, th):
-        """Same [T, B, I] tile through fused vs fused_batch: bit-identical
-        (same kernel, same union compaction — the batched name adds only
-        the contract), in jnp-ref AND Pallas-interpret modes."""
+        """One [T, B, I] GRU tile through fused equals each stream run
+        alone, in jnp-ref AND Pallas-interpret modes."""
         params, xs = _gru_stack_and_xs(key=1, b=4)
-        want, _, st_f = deltagru_sequence(params, xs, tx, th,
-                                          backend="fused",
-                                          interpret=interpret)
-        got, _, st_b = deltagru_sequence(params, xs, tx, th,
-                                         backend="fused_batch",
-                                         interpret=interpret)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert float(st_b["gamma_dx"]) == float(st_f["gamma_dx"])
-        assert float(st_b["gamma_dh"]) == float(st_f["gamma_dh"])
+        _assert_fp32_tile_equals_solo(deltagru_sequence, params, xs, tx, th,
+                                      interpret)
 
     @pytest.mark.parametrize("interpret", [None, True])
     @pytest.mark.parametrize("tx,th", THETAS)
     def test_lstm_fp32_bit_identical_to_fused_on_same_tile(self, interpret,
                                                            tx, th):
-        params = init_lstm_stack(jax.random.PRNGKey(2), 12, 24, 2)
-        xs = jax.random.normal(jax.random.PRNGKey(3), (14, 3, 12)) * 0.5
-        want, _, _ = deltalstm_sequence(params, xs, tx, th, backend="fused",
-                                        interpret=interpret)
-        got, _, _ = deltalstm_sequence(params, xs, tx, th,
-                                       backend="fused_batch",
-                                       interpret=interpret)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        params, xs = _lstm_stack_and_xs()
+        _assert_fp32_tile_equals_solo(deltalstm_sequence, params, xs, tx, th,
+                                      interpret)
 
-    @pytest.mark.parametrize("cell", ["gru", "lstm"])
+    @pytest.mark.parametrize("cell", CELLS)
     def test_q8_code_exact_to_fused_q8_on_same_tile(self, cell):
+        """One [T, B, I] tile through fused_q8 and fused_q4 is code-exact
+        to each stream run alone at [T, 1, I]: outputs and final state."""
         if cell == "gru":
             params, xs = _gru_stack_and_xs(key=4, b=3)
         else:
-            params = init_lstm_stack(jax.random.PRNGKey(5), 12, 24, 2)
-            xs = jax.random.normal(jax.random.PRNGKey(6), (14, 3, 12)) * 0.5
-        want, _, _ = (deltagru_sequence if cell == "gru"
-                      else deltalstm_sequence)(
-            params, xs, 0.05, 0.1, backend="fused_q8")
-        got, _, _ = (deltagru_sequence if cell == "gru"
-                     else deltalstm_sequence)(
-            params, xs, 0.05, 0.1, backend="fused_q8_batch")
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            params, xs = _lstm_stack_and_xs(key=5)
+        for backend in ("fused_q8", "fused_q4"):
+            prog = compile_delta_program(params, backend=backend, cell=cell)
+            got, final, _ = prog.sequence(xs, 0.05, 0.1)
+            for s in range(xs.shape[1]):
+                want, final_s, _ = prog.sequence(xs[:, s:s + 1], 0.05, 0.1)
+                np.testing.assert_array_equal(np.asarray(got)[:, s],
+                                              np.asarray(want)[:, 0])
+                for a, b in zip(jax.tree_util.tree_leaves(final),
+                                jax.tree_util.tree_leaves(final_s)):
+                    np.testing.assert_array_equal(np.asarray(a)[s],
+                                                  np.asarray(b)[0])
 
 
 class TestCompanionStreamIndependence:
@@ -199,10 +133,10 @@ class TestCompanionStreamIndependence:
         params = init_gru_stack(jax.random.PRNGKey(8), 14, 32, 2)
         xs_a, xs_b = self._tiles()
         ya, _, _ = deltagru_sequence(params, xs_a, 0.05, 0.1,
-                                     backend="fused_batch",
+                                     backend="fused",
                                      interpret=interpret)
         yb, _, _ = deltagru_sequence(params, xs_b, 0.05, 0.1,
-                                     backend="fused_batch",
+                                     backend="fused",
                                      interpret=interpret)
         np.testing.assert_array_equal(np.asarray(ya)[:, 0],
                                       np.asarray(yb)[:, 0])
@@ -214,9 +148,9 @@ class TestCompanionStreamIndependence:
         params = init_gru_stack(jax.random.PRNGKey(9), 14, 32, 2)
         xs_a, xs_b = self._tiles(key=10)
         ya, _, _ = deltagru_sequence(params, xs_a, 0.05, 0.1,
-                                     backend="fused_q8_batch")
+                                     backend="fused_q8")
         yb, _, _ = deltagru_sequence(params, xs_b, 0.05, 0.1,
-                                     backend="fused_q8_batch")
+                                     backend="fused_q8")
         np.testing.assert_array_equal(np.asarray(ya)[:, 0],
                                       np.asarray(yb)[:, 0])
 
@@ -226,13 +160,11 @@ class TestCompanionStreamIndependence:
         stream served alone."""
         params, xs = _gru_stack_and_xs(key=11, b=4)
         qparams, layouts = quantize_stack(params)
-        prog = compile_delta_program(qparams, backend="fused_q8_batch",
-                                     layouts=layouts)
-        solo = compile_delta_program(qparams, backend="fused_q8",
+        prog = compile_delta_program(qparams, backend="fused_q8",
                                      layouts=layouts)
         got, _, _ = prog.sequence(xs, 0.05, 0.1)
         for s in range(xs.shape[1]):
-            want, _, _ = solo.sequence(xs[:, s:s + 1], 0.05, 0.1)
+            want, _, _ = prog.sequence(xs[:, s:s + 1], 0.05, 0.1)
             np.testing.assert_array_equal(np.asarray(got)[:, s],
                                           np.asarray(want)[:, 0])
 
@@ -241,7 +173,7 @@ class TestCompanionStreamIndependence:
         results move ~1 ulp with the row count), but it is tight."""
         params, xs = _gru_stack_and_xs(key=12, b=4)
         got, _, _ = deltagru_sequence(params, xs, 0.05, 0.1,
-                                      backend="fused_batch")
+                                      backend="fused")
         for s in range(xs.shape[1]):
             want, _, _ = deltagru_sequence(params, xs[:, s:s + 1], 0.05, 0.1,
                                            backend="fused")
@@ -274,33 +206,96 @@ class TestEngineRouting:
         return GruTaskConfig(8, 16, 2, 3, task="regression",
                              theta_x=theta, theta_h=theta)
 
-    def test_multi_stream_routes_to_tile_backend(self):
+    @pytest.mark.parametrize("n_streams", [1, 3])
+    @pytest.mark.parametrize("backend", ("dense",) + FUSED)
+    @pytest.mark.parametrize("cell", CELLS)
+    def test_tile_pricing_follows_n_streams(self, cell, backend, n_streams):
+        """The engine serves the program it was given at any width, names
+        it in report(), and prices a tile exactly where n_streams > 1 and
+        the kernel compacts on the union (every fused backend)."""
         task = self._task()
-        params = init_gru_model(jax.random.PRNGKey(0), task)
-        eng = GruStreamEngine(params, task, n_streams=3)
-        assert eng.backend == "fused_batch"
+        init = init_gru_model if cell == "gru" else init_lstm_model
+        prog = compile_delta_program(init(jax.random.PRNGKey(0), task),
+                                     backend=backend, cell=cell)
+        eng = DeltaStreamEngine(prog, task, n_streams=n_streams)
+        assert eng.program is prog
+        rng = np.random.default_rng(1)
+        eng.step_many(rng.normal(size=(6, n_streams, 8)).astype(np.float32))
         rep = eng.report()
-        assert rep["weight_fetch"] == "tile"
+        assert rep["backend"] == eng.backend == backend
+        tile = n_streams > 1 and backend != "dense"
+        assert rep["weight_fetch"] == ("tile" if tile else "stream")
+        tile_keys = {"union_gamma_dx", "union_gamma_dh",
+                     "tile_est_latency_us", "tile_weight_bytes_per_step",
+                     "weight_bytes_per_stream_per_step"}
+        assert tile_keys & rep.keys() == (tile_keys if tile else set())
+        if tile:
+            assert rep["weight_bytes_per_stream_per_step"] == (
+                rep["tile_weight_bytes_per_step"] / n_streams)
 
-    def test_q8_multi_stream_routes_to_q8_tile_backend(self):
-        task = self._task()
-        params = init_gru_model(jax.random.PRNGKey(0), task)
-        eng = GruStreamEngine(params, task, backend="fused_q8", n_streams=2)
-        assert eng.backend == "fused_q8_batch"
+    @pytest.mark.parametrize("n_streams", [1, 3])
+    @pytest.mark.parametrize("cell", CELLS)
+    def test_matched_firing_bytes_ladder(self, cell, n_streams):
+        """At matched firing the streamed bytes follow the weight width
+        exactly, q4 = 0.5 q8 = 0.125 fp32, per stream and per tile. Firing
+        is matched across widths by construction: one layer, every input
+        component jumps by >= 1.0 each frame on the Q8.8 grid (all fire
+        past theta_x = 0.25), and theta_h = 4 exceeds any hidden step."""
+        task = GruTaskConfig(8, 16, 1, 3, task="regression",
+                             theta_x=0.25, theta_h=4.0)
+        init = init_gru_model if cell == "gru" else init_lstm_model
+        params = init(jax.random.PRNGKey(3), task)
+        rng = np.random.default_rng(4)
+        mag = rng.integers(128, 257, size=(n_streams, 8)) / 256.0
+        sign = np.where(np.arange(10) % 2 == 0, 1.0, -1.0)
+        xs = (sign[:, None, None] * mag[None]).astype(np.float32)
+        reps = {}
+        for backend in FUSED:
+            eng = DeltaStreamEngine(
+                compile_delta_program(params, backend=backend, cell=cell),
+                task, n_streams=n_streams)
+            eng.step_many(xs)
+            reps[backend] = rep = eng.report()
+            assert rep["gamma_dx"] == 0.0 and rep["gamma_dh"] == 1.0
+        per_stream = {b: r["mean_weight_bytes_per_step"]
+                      for b, r in reps.items()}
+        assert per_stream["fused_q4"] > 0
+        assert per_stream["fused_q4"] == 0.5 * per_stream["fused_q8"] \
+            == 0.125 * per_stream["fused"]
+        if n_streams > 1:
+            tile = {b: r["tile_weight_bytes_per_step"]
+                    for b, r in reps.items()}
+            assert tile == per_stream   # the union fires what each fires
+            assert tile["fused_q4"] == 0.5 * tile["fused_q8"] \
+                == 0.125 * tile["fused"]
+            for b, r in reps.items():
+                assert r["weight_bytes_per_stream_per_step"] == (
+                    tile[b] / n_streams)
 
-    def test_single_stream_stays_per_stream(self):
+    @pytest.mark.parametrize("backend", FUSED)
+    @pytest.mark.parametrize("cell", CELLS)
+    def test_replicated_tile_fetch_equals_one_stream(self, cell, backend):
+        """A tile of one stream replicated three times fetches exactly
+        what the stream fetches alone (union compaction collapses
+        identical streams), so each stream pays a third of it."""
         task = self._task()
-        params = init_gru_model(jax.random.PRNGKey(0), task)
-        eng = GruStreamEngine(params, task)
-        assert eng.backend == "fused"
-        assert eng.report()["weight_fetch"] == "stream"
-
-    def test_dense_has_no_tile_sibling_and_stays_dense(self):
-        task = self._task()
-        params = init_gru_model(jax.random.PRNGKey(0), task)
-        eng = GruStreamEngine(params, task, backend="dense", n_streams=3)
-        assert eng.backend == "dense"
-        assert eng.report()["weight_fetch"] == "stream"
+        init = init_gru_model if cell == "gru" else init_lstm_model
+        prog = compile_delta_program(init(jax.random.PRNGKey(5), task),
+                                     backend=backend, cell=cell)
+        rng = np.random.default_rng(6)
+        walk = np.cumsum(rng.normal(size=(12, 1, 8)) * 0.1,
+                         axis=0).astype(np.float32)
+        solo = DeltaStreamEngine(prog, task)
+        solo.step_many(walk)
+        tile = DeltaStreamEngine(prog, task, n_streams=3)
+        tile.step_many(np.tile(walk, (1, 3, 1)))
+        r1, r3 = solo.report(), tile.report()
+        assert r3["union_gamma_dx"] == r1["gamma_dx"]
+        assert r3["union_gamma_dh"] == r1["gamma_dh"]
+        assert r3["tile_weight_bytes_per_step"] == \
+            r1["mean_weight_bytes_per_step"]
+        assert r3["weight_bytes_per_stream_per_step"] == \
+            r1["mean_weight_bytes_per_step"] / 3
 
     def test_tile_report_prices_union_firing(self):
         """Tile economics in report(): the union fires at least as much as
@@ -355,14 +350,15 @@ class TestEngineRouting:
             assert r1[key] == pytest.approx(r2[key], rel=1e-5), key
 
     def test_batcher_slot_recycling_isolated_on_tile_backend(self):
-        """Slot recycling through the batcher on a ROUTED (tile-fetch)
-        engine: a quiet successor admitted into a loud predecessor's slot
-        reports only its own served-alone accounting, even though both
+        """Slot recycling through the batcher on a tile-fetch engine: a
+        quiet successor admitted into a loud predecessor's slot reports
+        only its own served-alone accounting, even though both
         rode tiles whose union fetch the predecessor dominated."""
         task = self._task()
         params = init_gru_model(jax.random.PRNGKey(5), task)
-        eng = GruStreamEngine(params, task, n_streams=2)
-        assert eng.backend == "fused_batch"
+        prog = compile_delta_program(params)
+        eng = GruStreamEngine(prog, task, n_streams=2)
+        assert eng.program is prog and eng.report()["weight_fetch"] == "tile"
         cb = GruStreamBatcher(eng)
         rng = np.random.default_rng(6)
         loud = [(3.0 * rng.normal(size=(6, 8))).astype(np.float32)

@@ -52,7 +52,8 @@ def test_batcher_phase_routes_to_tile_and_matches_oracle(params):
     r = chip_smoke.phase_batcher(params, TASK, streams, n_streams=4,
                                  interpret=True)
     assert r["ok"], r
-    assert r["backend"] == "fused_q8_batch" and r["max_abs_diff"] == 0.0
+    assert r["backend"] == "fused_q8" and r["weight_fetch"] == "tile"
+    assert r["max_abs_diff"] == 0.0
 
 
 def test_fleet_phase_on_four_devices(params):

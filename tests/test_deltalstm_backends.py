@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backends import backend_names, get_backend
+from repro.core.backends import get_backend, list_backends
 from repro.core.deltalstm import (deltalstm_sequence, deltalstm_stack_step,
                                   deltalstm_step, init_deltalstm_stack_state,
                                   init_deltalstm_state, init_lstm_layer,
@@ -44,7 +44,7 @@ def _stack_and_xs(key=0, i=10, h=24, layers=2, t=14, b=2, scale=0.5):
 class TestLstmRegistry:
     def test_fused_registered(self):
         assert set(("dense", "fused", "fused_q8")) <= set(
-            backend_names("lstm"))
+            list_backends("lstm"))
 
     def test_spec_fields(self):
         spec = get_backend("fused", cell="lstm")
@@ -248,6 +248,7 @@ class TestLstmStreaming:
         task, model = self._task_model(key=2)
         prog = compile_delta_program(model, cell="lstm", backend="fused")
         eng = DeltaStreamEngine(prog, task, n_streams=2)
+        assert eng.program is prog and eng.report()["backend"] == "fused"
         cb = GruStreamBatcher(eng)
         rng = np.random.default_rng(0)
         seqs = [rng.normal(size=(t, 8)).astype(np.float32)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backends import backend_names, get_backend
+from repro.core.backends import get_backend, list_backends
 from repro.core.deltarglru import (CONV_WIDTH, deltarglru_sequence,
                                    deltarglru_step, init_deltarglru_model,
                                    init_deltarglru_stack,
@@ -66,7 +66,7 @@ def _delta_chain(pd, xs, theta=0.0, backend="dense", interpret=None):
 
 class TestRegistry:
     def test_backends_registered(self):
-        assert set(("dense", "fused")) <= set(backend_names("rglru"))
+        assert set(("dense", "fused")) <= set(list_backends("rglru"))
 
     def test_spec_fields(self):
         for name in ("dense", "fused"):
@@ -207,3 +207,22 @@ class TestEngine:
                                                       w_weight_bits=32)
         assert rep["gamma_dx"] > 0.0
         assert rep["mean_weight_bytes_per_step"] < dense_bytes
+
+    @pytest.mark.parametrize("backend", ["dense", "fused"])
+    def test_reduced_recipe_theta0_exact(self, backend):
+        """The reduced recurrentgemma-9b serving recipe at theta=0: the engine measures
+        no skipped column and prices exactly the dense projection volume,
+        on both backends."""
+        from repro.configs.recurrentgemma_9b import reduced_delta_recipe
+        cfg, model, task = reduced_delta_recipe(jax.random.PRNGKey(0))
+        prog = compile_delta_program(model, backend=backend, cell="rglru")
+        eng = DeltaStreamEngine(prog, task)
+        xs = np.asarray(jax.random.normal(jax.random.PRNGKey(1),
+                                          (8, cfg.d_model)), np.float32)
+        eng.step_many(xs)
+        rep = eng.report()
+        assert rep["gamma_dx"] == 0.0 and rep["gamma_dh"] == 0.0
+        dims = cell_dims("rglru", task.input_size, task.hidden_size,
+                         task.num_layers)
+        assert rep["mean_weight_bytes_per_step"] == \
+            dram_traffic_bytes_per_timestep(dims, 0.0, 0.0, w_weight_bits=32)

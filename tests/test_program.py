@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backends import (BackendSpec, backend_names, get_backend,
+from repro.core.backends import (BackendSpec, get_backend, list_backends,
                                  register_backend, unregister_backend)
 from repro.core.deltagru import (deltagru_sequence, deltagru_stack_step,
                                  init_deltagru_stack_state, init_gru_stack,
@@ -24,8 +24,7 @@ from repro.quant.export import quantize_gru_model
 from repro.serve.engine import GruStreamEngine
 from repro.serve.scheduler import GruStreamBatcher
 
-ALL_BACKENDS = ("dense", "fused", "fused_q8", "fused_batch",
-                "fused_q8_batch")
+ALL_BACKENDS = ("dense", "fused", "fused_q8", "fused_q4")
 
 
 def _stack_and_xs(key=0, i=10, h=24, layers=2, t=14, b=2):
@@ -37,13 +36,15 @@ def _stack_and_xs(key=0, i=10, h=24, layers=2, t=14, b=2):
 
 class TestRegistry:
     def test_builtin_backends_registered(self):
-        assert set(ALL_BACKENDS) <= set(backend_names("gru"))
-        assert "dense" in backend_names("lstm")
+        # one registration per kernel: a tile of streams is the call's
+        # shape, not a backend name
+        assert list_backends("gru") == ALL_BACKENDS
+        assert list_backends("lstm") == ALL_BACKENDS
 
     def test_spec_fields(self):
         assert get_backend("fused_q8").m_init == "zero"
         assert get_backend("fused_q8").weight_bits == 8
-        for be in ("dense", "fused", "fused_batch"):
+        for be in ("dense", "fused"):
             assert get_backend(be).m_init == "bias"
             assert get_backend(be).weight_bits == 32
         assert not get_backend("fused").supports_custom_acts
@@ -114,7 +115,7 @@ class TestCompileEquivalence:
         """Programs pass through jit as arguments (layers/layouts are
         leaves, backend is static)."""
         params, xs = _stack_and_xs()
-        for backend in ("fused", "fused_q8", "fused_batch"):
+        for backend in ("fused", "fused_q8", "fused_q4"):
             prog = compile_deltagru(params, backend=backend)
             fn = jax.jit(lambda p, xs: p.sequence(
                 xs, 0.05, 0.1, collect_sparsity=False)[0])

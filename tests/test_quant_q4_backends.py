@@ -17,13 +17,10 @@ Also pinned here: the nibble pack/unpack round trip (incl. odd raw
 ``bits=4``, the ``bits`` validation errors, the QAT W4 policy, the
 double-buffered weight-streaming parity (buffered == unbuffered, bitwise,
 both cells and both widths), the exact Eq. 7 pricing ladder
-(q4 = 0.5x q8 = 0.125x fp32) including the bench tooling's
-bytes-per-weight map (the ``bits // 8`` truncation regression), and
+(q4 = 0.5x q8 = 0.125x fp32) including the registry's bytes-per-weight
+map (the ``bits // 8`` truncation regression), and
 batcher session parity on quantized-int4 programs.
 """
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,7 +32,7 @@ from repro.core.deltalstm import deltalstm_sequence, init_lstm_stack
 from repro.core.perf_model import (backend_weight_bits,
                                    dram_traffic_bytes_per_timestep)
 from repro.core.program import compile_delta_program
-from repro.core.sparsity import lstm_dims
+from repro.core.sparsity import GruDims, lstm_dims
 from repro.kernels.delta_q8 import (deltagru_q8_step, deltalstm_q8_step,
                                     pack_delta_weights_q4,
                                     pack_delta_weights_q8, pack_nibbles,
@@ -442,10 +439,9 @@ class TestQ4Exporter:
 
     def test_fused_q4_in_registry_lists(self):
         for cell in ("gru", "lstm"):
-            assert "fused_q4" in list_backends(cell)
-            assert "fused_q4_batch" in list_backends(cell)
+            assert list_backends(cell)[-1] == "fused_q4"
         assert backend_weight_bits("gru")["fused_q4"] == 4
-        assert backend_weight_bits("lstm")["fused_q4_batch"] == 4
+        assert backend_weight_bits("lstm")["fused_q4"] == 4
 
 
 class TestQ4Pricing:
@@ -489,33 +485,30 @@ class TestQ4Pricing:
         assert 1.5 < ratio < 3.0
 
     def test_bench_bytes_map_not_truncated(self):
-        """Regression for the bench tooling's ``bits // 8`` truncation:
-        at 4 bits the bytes-per-weight map must be 0.5, not 0, and the
-        modeled bench bytes must come out at exactly half of q8 at the
-        same (matched) firing counts."""
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        if root not in sys.path:
-            sys.path.insert(0, root)
-        from benchmarks.kernel_bench import (_backend_weight_bytes,
-                                             _bytes_per_step)
-        for cell in ("gru", "lstm"):
-            wb = _backend_weight_bytes(cell)
-            assert wb["fused_q4"] == 0.5
-            assert wb["fused_q8"] == 1.0
-            assert wb["fused"] == 4.0
-        params = init_gru_stack(jax.random.PRNGKey(0), 16, 32, 2)
-        counts = np.array([[1.0, 2.0], [2.0, 1.0]])
-        b_q4 = _bytes_per_step(params, counts, "fused_q4", block=16)
-        b_q8 = _bytes_per_step(params, counts, "fused_q8", block=16)
-        assert b_q4 > 0 and b_q4 == 0.5 * b_q8
+        """Regression for a ``bits // 8`` truncation: priced from the
+        registry's widths, a 4-bit weight is 0.5 bytes, not 0, and the
+        modeled bytes come out at exactly half of q8 at the same
+        (matched) firing, for both cells."""
+        for cell, dims in (("gru", GruDims(16, 32, 2)),
+                           ("lstm", lstm_dims(16, 32, 2))):
+            bits = backend_weight_bits(cell)
+            assert {b: bits[b] / 8 for b in ("fused", "fused_q8",
+                                             "fused_q4")} == {
+                "fused": 4.0, "fused_q8": 1.0, "fused_q4": 0.5}
+            b_q4, b_q8 = (dram_traffic_bytes_per_timestep(
+                dims, 0.5, 0.25, w_weight_bits=bits[b])
+                for b in ("fused_q4", "fused_q8"))
+            assert b_q4 > 0 and b_q4 == 0.5 * b_q8
 
     def test_batcher_sessions_on_q4_lstm(self):
-        """int4 LSTM streams recycle through batcher sessions (auto-routed
-        onto fused_q4_batch) with per-stream accounting identical to
+        """int4 LSTM streams recycle through batcher sessions (one
+        fused_q4 tile per tick) with per-stream accounting identical to
         dedicated engines."""
         task, _, _, qprog4 = self._task_and_progs(key=2)
         eng = DeltaStreamEngine(qprog4, task, n_streams=2)
-        assert eng.program.backend == "fused_q4_batch"
+        assert eng.program is qprog4
+        rep = eng.report()
+        assert rep["backend"] == "fused_q4" and rep["weight_fetch"] == "tile"
         cb = GruStreamBatcher(eng)
         rng = np.random.default_rng(0)
         seqs = [rng.normal(size=(t, 10)).astype(np.float32)

@@ -562,8 +562,7 @@ class TestChaosSoak:
         assert checked == statuses["ok"]
 
         # determinism: the identical seeded soak reproduces every
-        # tick-based counter and status exactly (this is what lets
-        # check_regression gate them as hard numbers)
+        # tick-based counter and status exactly
         results2, srv2, restarts2 = self._run(str(tmp_path / "b"))
         assert restarts2 == restarts
         wall_keys = ("straggler_flags", "missed_heartbeats")
@@ -611,3 +610,28 @@ class TestServeResumableRestore:
         with pytest.raises(SimulatedCrash):
             serve_resumable(prog, TASK, arrivals, pol, n_streams=2,
                             fault_plan=AlwaysCrash())
+
+    def test_overload_flood_completes_every_stream(self):
+        """A fault-free flood through ``serve_resumable`` with the
+        overload controller on: Θ_h rises above its baseline under queue
+        pressure, every stream still completes, and idle ticks bring Θ_h
+        back to its baseline."""
+        rng = np.random.default_rng(1235)
+        flood, t = [], 0
+        for _ in range(24):
+            flood.append((t, _frames(int(rng.integers(5, 30)), rng)))
+            t += int(rng.integers(0, 2))
+        pol = ResiliencePolicy(max_queue=256, deadline_ticks=None,
+                               check_every=4, overload_queue=4,
+                               theta_max=0.5)
+        results, srv, restarts = serve_resumable(_program("fused_q8"), TASK,
+                                                 flood, pol, n_streams=8)
+        assert restarts == 0
+        assert len(results) == len(flood)
+        assert all(r.status == "ok" for r in results.values())
+        base = float(srv._theta_base)
+        assert srv.theta_peak > base
+        for _ in range(pol.check_every * 12):
+            srv.tick()
+        # exact at the carry's float32 precision
+        assert np.float32(srv.engine.theta_h) == np.float32(base)
